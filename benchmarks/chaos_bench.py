@@ -55,6 +55,7 @@ from repro.core import kdtree as kdtree_mod  # noqa: E402
 from repro.obs import meters as obs_meters  # noqa: E402
 from repro.runtime import elastic  # noqa: E402
 from repro.runtime.chaos import ChaosConfig, ChaosInjector  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 # The kill-and-resume smoke's shared shape: the child and the parent's
 # uninterrupted reference must build the exact same run.
@@ -266,6 +267,7 @@ def kill_resume_smoke() -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--cycles", type=int, default=12)
     ap.add_argument("--m", type=int, default=120)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.compile_cache import use_compile_cache
 
 
 def _time(fn, repeats=5):
@@ -109,5 +110,6 @@ def bench_all(mode_fast: str = "ref"):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     for name, us, derived in bench_all():
         print(f"{name},{us:.1f},{derived}")

@@ -14,6 +14,8 @@ sys.path.insert(0, __file__.rsplit("/", 2)[0] + "/src")
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="smaller p-sweeps (CI mode)")

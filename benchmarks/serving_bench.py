@@ -41,8 +41,8 @@ import numpy as np  # noqa: E402
 
 from repro.assim import (  # noqa: E402
     AssimilationEngine, EngineConfig, FleetServer, streams)
-from repro.core import _compat  # noqa: E402
 from repro.obs import meters as obs_meters  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 
 def scenario_pool(args):
@@ -129,6 +129,7 @@ def run_fleet(specs, args, mesh, solver=None) -> tuple:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--streams", type=int, nargs="+", default=[16, 64],
                     help="fleet sizes to sweep (paper curve: 16 64 256 "
@@ -165,7 +166,8 @@ def main() -> None:
     n_dev = len(jax.devices())
     mesh = None
     if not args.no_mesh and n_dev > 1:
-        mesh = _compat.make_device_mesh((n_dev,), ("fleet",))
+        mesh = jax.make_mesh((n_dev,), ("fleet",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
     report = {
         "bench_config": {k: v for k, v in vars(args).items()

@@ -50,6 +50,7 @@ from repro.core import ddkf, domain, kdtree  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.obs import meters as obs_meters  # noqa: E402
 from repro.obs import trace as obs_trace  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 
 def make_config(ndim: int, rebalance: bool, args,
@@ -271,6 +272,7 @@ def pint_section(args) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n", type=int, default=256, help="1D state dimension")
     ap.add_argument("--p", type=int, default=8, help="1D subdomains")

@@ -39,6 +39,7 @@ import numpy as np  # noqa: E402
 from repro.assim import AssimilationEngine, EngineConfig, streams  # noqa: E402
 from repro.core import ddkf  # noqa: E402
 from repro.obs import trace as obs_trace  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 
 def make_config(args) -> EngineConfig:
@@ -122,6 +123,7 @@ def run_scenario(name: str, args) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--ndim", type=int, default=1, choices=(1, 2),
                     help="domain dimension: 1 = interval, 2 = shelf tiling "

@@ -14,9 +14,11 @@ import numpy as np  # noqa: E402
 
 from repro.core import cls, dd, ddkf, dydd, kalman  # noqa: E402
 from repro.data import observations  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 
 def main():
+    use_compile_cache()
     n, m, p = 512, 1200, 8
 
     # 1. A CLS state-estimation problem with spatially clustered (sparse,

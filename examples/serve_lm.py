@@ -11,9 +11,11 @@ import jax
 from repro import configs
 from repro.launch.serve import Request, serve_batch
 from repro.models import transformer
+from repro.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b")
     ap.add_argument("--batch", type=int, default=4)

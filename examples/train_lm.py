@@ -13,9 +13,11 @@ import tempfile
 
 from repro import configs
 from repro.launch.train import train
+from repro.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--tiny", action="store_true")

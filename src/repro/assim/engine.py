@@ -45,7 +45,6 @@ from repro.core import ddkf as ddkf_mod
 from repro.core import domain as domain_mod
 from repro.core import dydd as dydd_mod
 from repro.core import kdtree as kdtree_mod
-from repro.core import _compat as compat_mod
 from repro.checkpoint import manager as ckpt_mod
 from repro.kernels import ops as ops_mod
 from repro.obs import meters as meters_mod
@@ -400,7 +399,9 @@ class AssimilationEngine:
                     f"XLA_FLAGS=--xla_force_host_platform_device_count="
                     f"{self.p} to fan a host platform out, or match the "
                     f"config's p/pr*pc to the hardware")
-            mesh = compat_mod.make_device_mesh(shape, names)
+            mesh = jax.make_mesh(
+                tuple(shape), tuple(names),
+                axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
             return mesh, (names if len(names) > 1 else names[0])
         n_mesh = int(np.prod(list(mesh.shape.values())))
         if n_mesh != self.p:

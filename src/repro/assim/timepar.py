@@ -60,7 +60,6 @@ import numpy as np
 import jax
 
 from repro.core import ddkf as ddkf_mod
-from repro.core import _compat as compat_mod
 from repro.obs import meters as meters_mod
 from repro.obs import trace as trace_mod
 from repro.runtime import chaos as chaos_mod
@@ -94,8 +93,9 @@ def resolve_time_mesh(time_windows: int, p: int, time_axis: str = "time",
             continue
         ks = ndev // kt
         if p % ks == 0:
-            return compat_mod.make_device_mesh((kt, ks),
-                                               (time_axis, sub_axis))
+            return jax.make_mesh(
+                (kt, ks), (time_axis, sub_axis),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
     return None
 
 

@@ -45,7 +45,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import cls as cls_mod
 from repro.core import dd as dd_mod
-from repro.core import _compat
 from repro.kernels import ops as ops_mod
 
 
@@ -564,8 +563,9 @@ def _fleet_sharded_fn(mesh, axis: str, iters: int, residual_history: bool):
                                     residual_history=residual_history),
             pk)
 
-    fn = jax.jit(_compat.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis)))
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
+        check_vma=False))
     _FLEET_SHARDED_CACHE[key] = fn
     return fn
 
@@ -692,13 +692,45 @@ def solve_shardmap(packed: PackedDD, mesh, axis="sub",
     if mvec not in ("psum", "scatter"):
         raise ValueError(f"mvec must be 'auto', 'psum' or 'scatter' "
                          f"(got {mvec!r})")
+    perms = halo.perms if comm == "neighbour" else ()
+    # Neighbour-path schedule arrays (sharded like the packing).  The
+    # perms and round count are static Python; only the per-device
+    # pack/unpack payload maps travel as operands — int32 end to end
+    # (the schedule indexes w + 1 <= 2^31 slots; int64 operands would
+    # silently downcast under default x32 and double the index payload).
+    empty = np.zeros((packed.p, 0, 0), np.int32)
+    pack_idx = jnp.asarray(halo.pack_idx if comm == "neighbour" else empty,
+                           jnp.int32)
+    unpack_idx = jnp.asarray(halo.unpack_idx if comm == "neighbour"
+                             else empty, jnp.int32)
+    fn = _shardmap_fn(mesh, axes, iters, comm, mvec, perms,
+                      residual_history, return_per_device)
+    out, hist = fn(packed, pack_idx, unpack_idx,
+                   jnp.asarray(damping, packed.A_loc.dtype))
+    if residual_history:
+        return out, hist
+    return out
+
+
+def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
+                 perms: tuple, residual_history: bool,
+                 return_per_device: bool):
+    """Jitted shard_map of the one-subdomain-per-device sweep (cached per
+    (mesh, axes, iters, comm, mvec, halo perms, residual_history,
+    return_per_device) — the packing's shapes and its static meta fields
+    (solve kernel, block) key jit's own trace cache, so a stream of
+    same-shape cycles compiles once)."""
+    key = (mesh, axes, iters, comm, mvec, perms, residual_history,
+           return_per_device)
+    fn = _SHARDMAP_CACHE.get(key)
+    if fn is not None:
+        return fn
     ppermute_axis = axes if len(axes) > 1 else axes[0]
+    rounds = len(perms)
     # Innermost axis carries the scatters; pad the reduced vectors so
     # their length splits evenly (the n-vector keeps one extra slot as
     # the -1-column dump).
     ks = int(mesh.shape[axes[-1]])
-    n_pad = -(-(packed.n + 1) // ks) * ks
-    m_pad = -(-packed.m // ks) * ks
 
     def axis_allreduce(part):
         """All-reduce a ks-divisible vector over every mesh axis: plain
@@ -710,123 +742,121 @@ def solve_shardmap(packed: PackedDD, mesh, axis="sub",
                                      tiled=True)
         return jax.lax.all_gather(chunk, axes[-1], tiled=True)
 
-    def mvec_allreduce(part):
-        if mvec == "psum":
-            return jax.lax.psum(part, axes)
-        pad = m_pad - packed.m
-        if pad:
-            part = jnp.concatenate([part, jnp.zeros((pad,), part.dtype)])
-        return axis_allreduce(part)[:packed.m]
+    def run(packed, pack_idx, unpack_idx, damping):
+        n, m, w, kern = packed.n, packed.m, packed.w, packed.solve_kernel
+        n_pad = -(-(n + 1) // ks) * ks
+        m_pad = -(-m // ks) * ks
 
-    # Neighbour-path schedule arrays (sharded like the packing).  The
-    # perms and round count are static Python; only the per-device
-    # pack/unpack payload maps travel as operands — int32 end to end
-    # (the schedule indexes w + 1 <= 2^31 slots; int64 operands would
-    # silently downcast under default x32 and double the index payload).
-    rounds = halo.rounds if comm == "neighbour" else 0
-    empty = np.zeros((packed.p, 0, 0), np.int32)
-    pack_idx = jnp.asarray(halo.pack_idx if comm == "neighbour" else empty,
-                           jnp.int32)
-    unpack_idx = jnp.asarray(halo.unpack_idx if comm == "neighbour"
-                             else empty, jnp.int32)
-    kern = packed.solve_kernel
+        def mvec_allreduce(part):
+            if mvec == "psum":
+                return jax.lax.psum(part, axes)
+            if m_pad > m:
+                part = jnp.concatenate(
+                    [part, jnp.zeros((m_pad - m,), part.dtype)])
+            return axis_allreduce(part)[:m]
 
-    def per_device(A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i,
-                   mloc_i, pack_i, unpack_i):
-        # Leading axis of size 1 (= this device's subdomain).
-        (A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i, mloc_i,
-         pack_i, unpack_i) = (A_i[0], L_i[0], mask_i[0], muov_i[0],
-                              wdiv_i[0], scat_i[0], gath_i[0], mloc_i[0],
-                              pack_i[0], unpack_i[0])
+        def per_device(A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i,
+                       mloc_i, pack_i, unpack_i, r, b, mult, damping):
+            # Leading axis of size 1 (= this device's subdomain).
+            (A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i, mloc_i,
+             pack_i, unpack_i) = (A_i[0], L_i[0], mask_i[0], muov_i[0],
+                                  wdiv_i[0], scat_i[0], gath_i[0],
+                                  mloc_i[0], pack_i[0], unpack_i[0])
 
-        def scatter_part(x_i):
-            # scat_i parks padding on slot n (< n_pad): same dump trick.
-            return jnp.zeros((n_pad,), x_i.dtype).at[scat_i].add(
-                x_i * mask_i)
+            def scatter_part(x_i):
+                # scat_i parks padding on slot n (< n_pad): same dump
+                # trick.
+                return jnp.zeros((n_pad,), x_i.dtype).at[scat_i].add(
+                    x_i * mask_i)
 
-        def exchange_allreduce(x_i2):
-            # Overlap consistency (eq. 28): multiplicity-weighted average
-            # of the duplicated columns via the global assembly, then
-            # gather back.
-            x_glob = axis_allreduce(scatter_part(x_i2))[:packed.n] \
-                / packed.mult
-            return x_glob[gath_i] * mask_i
+            def exchange_allreduce(x_i2):
+                # Overlap consistency (eq. 28): multiplicity-weighted
+                # average of the duplicated columns via the global
+                # assembly, then gather back.
+                x_glob = axis_allreduce(scatter_part(x_i2))[:n] / mult
+                return x_glob[gath_i] * mask_i
 
-        def exchange_neighbour(x_i2):
-            # Same average, neighbour-only: own contribution plus the
-            # halo slots received over the directed coloured rounds,
-            # divided by the local multiplicity.  Each round is ONE
-            # ppermute of one packed h-lane buffer — this device gathers
-            # its outgoing payload at pack_idx (send partner) and
-            # scatter-adds the received buffer at unpack_idx (recv
-            # partner, not necessarily the same device) — exactly
-            # halo.rounds permutes per iteration however many edges meet
-            # here.  Slot w is the dump: it gathers zero (payload
-            # padding) and absorbs scatter padding.
-            xm = x_i2 * mask_i
-            acc = jnp.concatenate([xm, jnp.zeros((1,), xm.dtype)])
-            xm_pad = acc
-            for rnd in range(rounds):
-                buf = xm_pad[pack_i[rnd]]
-                got = jax.lax.ppermute(buf, ppermute_axis,
-                                       perm=halo.perms[rnd])
-                acc = acc.at[unpack_i[rnd]].add(got)
-            return acc[:packed.w] / mloc_i
+            def exchange_neighbour(x_i2):
+                # Same average, neighbour-only: own contribution plus the
+                # halo slots received over the directed coloured rounds,
+                # divided by the local multiplicity.  Each round is ONE
+                # ppermute of one packed h-lane buffer — this device
+                # gathers its outgoing payload at pack_idx (send partner)
+                # and scatter-adds the received buffer at unpack_idx
+                # (recv partner, not necessarily the same device) —
+                # exactly halo.rounds permutes per iteration however many
+                # edges meet here.  Slot w is the dump: it gathers zero
+                # (payload padding) and absorbs scatter padding.
+                xm = x_i2 * mask_i
+                acc = jnp.concatenate([xm, jnp.zeros((1,), xm.dtype)])
+                xm_pad = acc
+                for rnd in range(rounds):
+                    buf = xm_pad[pack_i[rnd]]
+                    got = jax.lax.ppermute(buf, ppermute_axis,
+                                           perm=perms[rnd])
+                    acc = acc.at[unpack_i[rnd]].add(got)
+                return acc[:w] / mloc_i
 
-        exchange = (exchange_neighbour if comm == "neighbour"
-                    else exchange_allreduce)
+            exchange = (exchange_neighbour if comm == "neighbour"
+                        else exchange_allreduce)
 
-        def step(x_i):
-            if kern == "jnp":
-                Ax = mvec_allreduce(A_i @ (x_i * wdiv_i))
-                new = _local_update(A_i, L_i, mask_i, muov_i, x_i, Ax,
-                                    packed.r, packed.b)
+            def step(x_i):
+                if kern == "jnp":
+                    Ax = mvec_allreduce(A_i @ (x_i * wdiv_i))
+                    new = _local_update(A_i, L_i, mask_i, muov_i, x_i, Ax,
+                                        r, b)
+                else:
+                    mode = _KERNEL_OPS_MODE[kern]
+                    y, u = ops_mod.schwarz_fwd(A_i[None], x_i[None],
+                                               wdiv_i[None], mode=mode,
+                                               block_m=packed.solve_block)
+                    Ax = mvec_allreduce(y[0])
+                    rhs = ops_mod.schwarz_bwd(A_i[None], r, b, Ax, u,
+                                              x_i[None], muov_i[None],
+                                              mask_i[None], mode=mode,
+                                              block_m=packed.solve_block)[0]
+                    new = _chol_solve(L_i, rhs) * mask_i
+                return exchange((1.0 - damping) * x_i + damping * new)
+
+            x_i = jnp.zeros((w,), dtype=A_i.dtype)
+            if residual_history:
+                # Per-iteration global update norm: local squared delta,
+                # psum'd over the whole mesh — every device carries the
+                # identical history (overlap slots count with
+                # multiplicity, matching solve_vmapped's (p, w) Frobenius
+                # norm).
+                def sbody(x_prev, _):
+                    nxt = step(x_prev)
+                    d2 = jax.lax.psum(jnp.sum((nxt - x_prev) ** 2), axes)
+                    return nxt, jnp.sqrt(d2)
+
+                x_i, hist = jax.lax.scan(sbody, x_i, None, length=iters)
             else:
-                mode = _KERNEL_OPS_MODE[kern]
-                y, u = ops_mod.schwarz_fwd(A_i[None], x_i[None],
-                                           wdiv_i[None], mode=mode,
-                                           block_m=packed.solve_block)
-                Ax = mvec_allreduce(y[0])
-                rhs = ops_mod.schwarz_bwd(A_i[None], packed.r, packed.b,
-                                          Ax, u, x_i[None], muov_i[None],
-                                          mask_i[None], mode=mode,
-                                          block_m=packed.solve_block)[0]
-                new = _chol_solve(L_i, rhs) * mask_i
-            return exchange((1.0 - damping) * x_i + damping * new)
+                x_i = jax.lax.fori_loop(0, iters, lambda _, x: step(x), x_i)
+                hist = jnp.zeros((0,), dtype=A_i.dtype)
+            # One full assembly at the end (both paths): emit the global
+            # estimate.  On the neighbour path this is the only O(n)
+            # collective of the whole solve.
+            return ((axis_allreduce(scatter_part(x_i))[:n] / mult)[None],
+                    hist[None])
 
-        x_i = jnp.zeros((packed.w,), dtype=A_i.dtype)
-        if residual_history:
-            # Per-iteration global update norm: local squared delta,
-            # psum'd over the whole mesh — every device carries the
-            # identical history (overlap slots count with multiplicity,
-            # matching solve_vmapped's (p, w) Frobenius norm).
-            def sbody(x_prev, _):
-                nxt = step(x_prev)
-                d2 = jax.lax.psum(jnp.sum((nxt - x_prev) ** 2), axes)
-                return nxt, jnp.sqrt(d2)
+        specs = P(axes if len(axes) > 1 else axes[0])
+        out, hist = jax.shard_map(
+            per_device, mesh=mesh,
+            in_specs=(specs,) * 10 + (P(),) * 4,
+            out_specs=(specs, specs), check_vma=False)(
+                packed.A_loc, packed.L_loc, packed.mask, packed.muov,
+                packed.wdiv, packed.scatter_cols, packed.gather_cols,
+                packed.mult_loc, pack_idx, unpack_idx, packed.r, packed.b,
+                packed.mult, damping)
+        return (out if return_per_device else out[0]), hist[0]
 
-            x_i, hist = jax.lax.scan(sbody, x_i, None, length=iters)
-        else:
-            x_i = jax.lax.fori_loop(0, iters, lambda _, x: step(x), x_i)
-            hist = jnp.zeros((0,), dtype=A_i.dtype)
-        # One full assembly at the end (both paths): emit the global
-        # estimate.  On the neighbour path this is the only O(n)
-        # collective of the whole solve.
-        return ((axis_allreduce(scatter_part(x_i))[:packed.n]
-                 / packed.mult)[None], hist[None])
+    fn = jax.jit(run)
+    _SHARDMAP_CACHE[key] = fn
+    return fn
 
-    specs = P(axes if len(axes) > 1 else axes[0])
-    fn = _compat.shard_map(
-        per_device, mesh=mesh,
-        in_specs=(specs,) * 10,
-        out_specs=(specs, specs))
-    out, hist = fn(packed.A_loc, packed.L_loc, packed.mask, packed.muov,
-                   packed.wdiv, packed.scatter_cols, packed.gather_cols,
-                   packed.mult_loc, pack_idx, unpack_idx)
-    x = out if return_per_device else out[0]
-    if residual_history:
-        return x, hist[0]
-    return x
+
+_SHARDMAP_CACHE: dict = {}
 
 
 # ---------------------------------------------------------------------------
@@ -893,10 +923,10 @@ def _window_sharded_fn(mesh, time_axis: str, sub_axis: str, iters: int,
 
     ws = P(time_axis, sub_axis)
     wt = P(time_axis)
-    fn = jax.jit(_compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(ws, ws, ws, ws, ws, ws, ws, wt, wt, wt, wt, P()),
-        out_specs=ws))
+        out_specs=ws, check_vma=False))
     _WINDOW_SHARDED_CACHE[key] = fn
     return fn
 

@@ -74,23 +74,34 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256, mode: str = "auto"):
 # timed sweep; every later call (and every jit retrace with the same
 # shape) hits the cache.
 
-GRAM_BLOCK_CANDIDATES = (64, 128, 256, 512, 1024)
+# block_m is the lane axis of every m-vector tile the kernels load (the
+# gram's r row, the Schwarz step's (2, bm) output and its r/b/Ax/u rows),
+# so the TPU compiler takes only multiples of 128 — or the whole of m,
+# which ``min(c, m)`` yields for short stacks.
+GRAM_BLOCK_CANDIDATES = (128, 256, 512, 1024)
 
-# Conservative VMEM budget for one grid step's working set: half of the
-# ~16 MiB a TPU core has, leaving headroom for double buffering and the
-# Mosaic scheduler's own allocations.  Candidates whose tile footprint
-# exceeds this are rejected without being timed (a sweep that OOMs the
-# kernel is worse than a slightly narrower candidate set) and recorded
-# in the tuning report.
-GRAM_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+# VMEM budget for one grid step's working set: three quarters of the
+# 16 MiB of scoped VMEM the TPU v5e compiler gives a Pallas kernel (the
+# limit it reports when a kernel overflows it).  The tile models below
+# were checked against the compiler's own need (the least
+# ``vmem_limit_bytes`` that compiles, Example 4's p = 8, m = 6094 at
+# w = 256, 640 and 1664): the gram model over-estimates it, the Schwarz
+# model is within 4%, which the last quarter covers.  Candidates whose
+# tile footprint exceeds this are rejected without being timed (a sweep
+# that overflows VMEM does not compile) and recorded in the tuning
+# report.
+GRAM_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 _GRAM_TUNE_CACHE: dict = {}
 
 
 def gram_tile_bytes(block_m: int, w: int) -> int:
-    """f32 VMEM working set of one gram grid step: the (block_m, w) A
-    tile, its row-scaled copy, the (block_m,) r tile and the (w, w)
-    accumulator scratch."""
-    return 4 * (2 * block_m * w + block_m + w * w)
+    """f32 VMEM working set of one gram grid step, with bw the output
+    tile edge (``gram.GRAM_BLOCK_W``, or w when narrower): the
+    double-buffered (bw, bw) output tile and its accumulator, and the
+    two double-buffered (block_m, bw) A tiles with their row-scaled and
+    masked copies."""
+    bw = min(_gram.GRAM_BLOCK_W, w)
+    return 4 * (3 * bw * bw + 6 * block_m * bw)
 
 
 def autotune_gram_block(p: int, m: int, w: int, dtype,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _rglru_kernel(a_ref, b_ref, o_ref, h_ref, *, block_s: int):
     si = pl.program_id(2)
@@ -67,7 +65,7 @@ def rglru_scan(a, b, *, block_s: int = 256, block_w: int = 128,
                                (bi, si, wi)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_w,), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -25,21 +25,26 @@ that to one double-pass with no resid materialization:
 
 TPU mapping mirrors ``gram.py``: grid (p, m/bm) with the m axis
 sequential, accumulator in VMEM scratch, lane (w) axis padded to 128 by
-the wrapper in ops.py.  Unlike gram, f64 inputs keep an f64 accumulator
-(interpret mode must stay ULP-comparable to the jnp path; the f32
-accumulator is only used for f32/bf16 inputs where the MXU accumulates
-in f32 anyway).
+the wrapper in ops.py, and ``bm`` a multiple of 128 (or all of m) since
+it is the lane axis of the m-vector tiles.  Unlike gram, f64 inputs keep
+an f64 accumulator (interpret mode must stay ULP-comparable to the jnp
+path; the f32 accumulator is only used for f32/bf16 inputs where the MXU
+accumulates in f32 anyway).
 """
 from __future__ import annotations
 
 import functools
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+
+# Constant block index for the index maps: a bare 0 is traced as int64
+# when x64 is on, and Mosaic takes only int32 block indices.
+_ZERO = np.int32(0)
 
 
 def _acc_dtype(dtype):
@@ -50,10 +55,8 @@ def _fwd_kernel(a_ref, xs_ref, o_ref, *, acc_t):
     # One tile: (2, w) @ (bm, w)^T -> (2, bm) = [y_tile; u_tile].  Rows of
     # the output are independent dots over w, so padded m-rows need no
     # masking — out-of-range rows are dropped on writeback.
-    a = a_ref[0]                                   # (bm, w)
-    xs = xs_ref[0]                                 # (2, w)
-    o_ref[0] = jax.lax.dot_general(
-        xs, a, (((1,), (1,)), ((), ())),
+    o_ref[...] = jax.lax.dot_general(
+        xs_ref[...], a_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=acc_t).astype(o_ref.dtype)
 
 
@@ -70,12 +73,12 @@ def schwarz_fwd(A, x, wdiv, *, block_m: int = 256, interpret: bool = False):
         kernel,
         grid=(p, nm),
         in_specs=[
-            pl.BlockSpec((1, block_m, w), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 2, w), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, block_m, w), lambda i, j: (i, j, _ZERO)),
+            pl.BlockSpec((None, 2, w), lambda i, j: (i, _ZERO, _ZERO)),
         ],
-        out_specs=pl.BlockSpec((1, 2, block_m), lambda i, j: (i, 0, j)),
+        out_specs=pl.BlockSpec((None, 2, block_m), lambda i, j: (i, _ZERO, j)),
         out_shape=jax.ShapeDtypeStruct((p, 2, m), A.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(A, xs)
@@ -91,27 +94,26 @@ def _bwd_kernel(a_ref, r_ref, b_ref, ax_ref, u_ref, x_ref, muov_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[0]                                   # (bm, w)
+    acc_t = acc_ref.dtype
     # resid tile lives entirely in VMEM/registers — never written to HBM.
-    resid = b_ref[0] - ax_ref[0] + u_ref[0]        # (bm,)
-    t = (r_ref[0] * resid).astype(acc_ref.dtype)
+    resid = b_ref[...] - ax_ref[...] + u_ref[...]  # (1, bm)
+    t = (r_ref[...] * resid).astype(acc_t)
     # mask padded rows of the final block (and the A tile, so garbage
     # padding can't poison the product via 0 * inf)
+    col = mi * block_m + jax.lax.broadcasted_iota(jnp.int32,
+                                                  (1, block_m), 1)
     row = mi * block_m + jax.lax.broadcasted_iota(jnp.int32,
                                                   (block_m, 1), 0)
-    valid = row < m_total
-    t = jnp.where(valid[:, 0], t, 0.0)
-    a = jnp.where(valid, a, 0.0).astype(acc_ref.dtype)
+    t = jnp.where(col < m_total, t, 0.0)
+    a = jnp.where(row < m_total, a_ref[...], 0.0).astype(acc_t)
     acc_ref[...] += jax.lax.dot_general(
-        t[None, :], a, (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_ref.dtype)
+        t, a, (((1,), (0,)), ((), ())), preferred_element_type=acc_t)
 
     @pl.when(mi == nm - 1)
     def _done():
-        acc = acc_ref[0] + muov_ref[0].astype(acc_ref.dtype) * \
-            x_ref[0].astype(acc_ref.dtype)
-        o_ref[0] = (acc * mask_ref[0].astype(acc_ref.dtype)
-                    ).astype(o_ref.dtype)
+        acc = acc_ref[...] + (muov_ref[...].astype(acc_t)
+                              * x_ref[...].astype(acc_t))
+        o_ref[...] = (acc * mask_ref[...].astype(acc_t)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -119,31 +121,37 @@ def schwarz_bwd(A, r, b, Ax, u, x, muov, mask, *, block_m: int = 256,
                 interpret: bool = False):
     """A: (p, m, w), r/b/Ax: (m,), u: (p, m), x/muov/mask: (p, w) ->
     rhs: (p, w) = (A^T @ (r * (b - Ax + u)) + muov * x) * mask, one HBM
-    pass over A with the resid tiles formed in VMEM."""
+    pass over A with the resid tiles formed in VMEM.
+
+    Every vector enters as a (1, len) row per grid step — the shared
+    m-vectors as (1, m), the per-subdomain ones lifted to (p, 1, len) —
+    so each block's last two dims are a full unit row by a lane tile,
+    which the TPU's (8, 128) tiling accepts at any p."""
     p, m, w = A.shape
     block_m = min(block_m, m)
     nm = pl.cdiv(m, block_m)
-    r2, b2, ax2 = r[None], b[None], Ax[None]       # (1, m)
     kernel = functools.partial(_bwd_kernel, block_m=block_m, m_total=m)
-    vec_spec = pl.BlockSpec((1, block_m), lambda i, j: (0, j))
-    loc_spec = pl.BlockSpec((1, w), lambda i, j: (i, 0))
-    return pl.pallas_call(
+    vec_spec = pl.BlockSpec((1, block_m), lambda i, j: (_ZERO, j))
+    loc_spec = pl.BlockSpec((None, 1, w), lambda i, j: (i, _ZERO, _ZERO))
+    out = pl.pallas_call(
         kernel,
         grid=(p, nm),
         in_specs=[
-            pl.BlockSpec((1, block_m, w), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((None, block_m, w), lambda i, j: (i, j, _ZERO)),
             vec_spec,                              # r
             vec_spec,                              # b
             vec_spec,                              # Ax
-            pl.BlockSpec((1, block_m), lambda i, j: (i, j)),  # u
+            pl.BlockSpec((None, 1, block_m), lambda i, j: (i, _ZERO, j)),  # u
             loc_spec,                              # x
             loc_spec,                              # muov
             loc_spec,                              # mask
         ],
         out_specs=loc_spec,
-        out_shape=jax.ShapeDtypeStruct((p, w), A.dtype),
+        out_shape=jax.ShapeDtypeStruct((p, 1, w), A.dtype),
         scratch_shapes=[pltpu.VMEM((1, w), _acc_dtype(A.dtype))],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(A, r2, b2, ax2, u, x, muov, mask)
+    )(A, r[None], b[None], Ax[None], u[:, None], x[:, None], muov[:, None],
+      mask[:, None])
+    return out[:, 0]

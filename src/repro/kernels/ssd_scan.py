@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, s_ref, *,
                 chunk: int):
@@ -92,7 +90,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 256,
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, B, C)

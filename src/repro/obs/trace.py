@@ -312,15 +312,11 @@ def emit(name: str, t0: float, dur: float, track: Optional[str] = None,
 def jax_profile(logdir: Optional[str]):
     """Optional ``jax.profiler.trace`` passthrough: profiles the block
     into ``logdir`` (TensorBoard/XPlane format) when a directory is
-    given and the profiler is available; a silent no-op otherwise."""
+    given, a no-op when none is.  A profiler that fails to start raises:
+    a run asked to profile never exits 0 without a profile."""
     if not logdir:
         yield None
         return
-    try:
-        import jax
-        ctx = jax.profiler.trace(logdir)
-    except Exception:                     # profiler unavailable: no-op
-        yield None
-        return
-    with ctx:
+    import jax
+    with jax.profiler.trace(logdir):
         yield logdir

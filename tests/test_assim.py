@@ -434,8 +434,9 @@ def test_shardmap_without_mesh_raises():
 def test_shardmap_mesh_device_count_mismatch_raises():
     """p != mesh device count must fail up front with an actionable
     message, not as an opaque shard_map shape error mid-solve."""
-    from repro.core import _compat
-    mesh = _compat.make_device_mesh((1,), ("sub",))
+    import jax
+    mesh = jax.make_mesh((1,), ("sub",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="one device per subdomain"):
         AssimilationEngine(EngineConfig(solver="shardmap", p=4), mesh=mesh)
 

@@ -402,6 +402,28 @@ print("UNREACHABLE")
     assert j.deterministic_json() == base.deterministic_json()
 
 
+def test_kill_resume_parent_holds_no_backend_at_spawn():
+    """The kill-and-resume smoke's parent must not hold an accelerator
+    while its child runs: a chip belongs to one process at a time, so a
+    parent that had initialised a backend would starve the child."""
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    out = _run_child(f"""
+import sys
+sys.path.insert(0, r"{bench}")
+import chaos_bench
+from jax._src import xla_bridge
+
+def spawn(*args, **kwargs):
+    print("BACKEND_AT_SPAWN", xla_bridge.backends_are_initialized())
+    sys.exit(0)
+
+chaos_bench.subprocess.run = spawn
+chaos_bench.kill_resume_smoke()
+""")
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "BACKEND_AT_SPAWN False" in out.stdout, out.stdout
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("kind", ["shelf", "kdtree"])
 @pytest.mark.parametrize("new_p", [4, 2])

@@ -193,7 +193,11 @@ def test_elastic_remesh_subprocess(tmp_path):
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import manager as ckpt
-from repro.core._compat import make_device_mesh
+from jax.sharding import AxisType
+
+def make_device_mesh(shape, names):
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 tree = {{"w": jnp.arange(64.0).reshape(8, 8)}}
 mesh1 = make_device_mesh((2, 2), ("data", "model"))

@@ -18,7 +18,7 @@ SCRIPT = r"""
 import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
-from repro.core import cls, dd, ddkf, dydd, _compat
+from repro.core import cls, dd, ddkf, dydd
 
 rng = np.random.default_rng(0)
 obs = rng.beta(2, 5, size=400)
@@ -27,7 +27,8 @@ x_direct = cls.solve(prob)
 res = dydd.dydd_1d(obs, 8)
 dec = dd.decompose_1d(prob.n, res.boundaries, overlap=0)
 packed = ddkf.pack(prob, dec)
-mesh = _compat.make_device_mesh((8,), ("sub",))
+mesh = jax.make_mesh((8,), ("sub",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x_s = ddkf.solve_shardmap(packed, mesh, axis="sub", iters=120)
 err = float(jnp.linalg.norm(x_s - x_direct))
 assert err < 1e-9, err
@@ -82,7 +83,7 @@ SCRIPT_2D = r"""
 import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
-from repro.core import cls, dd, ddkf, dydd2d, domain, _compat
+from repro.core import cls, dd, ddkf, dydd2d, domain
 
 ny, nx = 8, 16
 n = nx * ny
@@ -96,7 +97,8 @@ obs_raster = (np.clip((obs2[:, 1] * ny).astype(int), 0, ny - 1) * nx
 prob = cls.local_problem(jax.random.PRNGKey(0), n, np.sort(obs_raster))
 packed = ddkf.pack(prob, dec)
 x_v = ddkf.solve_vmapped(packed, iters=200, damping=0.7)
-mesh = _compat.make_device_mesh((2, 4), ("row", "col"))
+mesh = jax.make_mesh((2, 4), ("row", "col"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 x_s = ddkf.solve_shardmap(packed, mesh, axis=("row", "col"), iters=200,
                           damping=0.7)
 # The grid-sharded solve runs the identical iteration; the collective
@@ -157,7 +159,7 @@ SCRIPT_KDTREE = r"""
 import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
-from repro.core import cls, ddkf, kdtree, _compat
+from repro.core import cls, ddkf, kdtree
 from repro.assim import streams, AssimilationEngine, EngineConfig
 
 # Irregular-graph halo exchange: a rebalanced 8-leaf k-d tree's face
@@ -172,7 +174,8 @@ assert len(he.edges) > 7, he.edges            # more than a chain
 prob = cls.local_problem(jax.random.PRNGKey(0), dom.n,
                          np.sort(dom.obs_positions(obs2)))
 packed = ddkf.pack(prob, dec)
-mesh = _compat.make_device_mesh((8,), ("sub",))
+mesh = jax.make_mesh((8,), ("sub",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x_a = ddkf.solve_shardmap(packed, mesh, axis="sub", iters=200, damping=0.7)
 x_n = ddkf.solve_shardmap(packed, mesh, axis="sub", iters=200, damping=0.7,
                           comm="neighbour", halo=he)

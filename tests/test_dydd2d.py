@@ -297,7 +297,10 @@ def test_dydd_2d_tie_ranks_thread_through_warm_start():
 # gram kernel.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("p,m,w", [(4, 300, 32), (2, 512, 64), (1, 64, 16)])
+# (1, 200, 600) spans two output tiles of GRAM_BLOCK_W = 512 lanes, the
+# second one ragged.
+@pytest.mark.parametrize("p,m,w", [(4, 300, 32), (2, 512, 64), (1, 64, 16),
+                                   (1, 200, 600)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gram_kernel_sweep(p, m, w, dtype):
     rng = np.random.default_rng(0)

@@ -327,7 +327,6 @@ import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np
 from repro.assim import AssimilationEngine, EngineConfig, FleetServer, streams
-from repro.core import _compat
 
 def recorder(store):
     def f(x):
@@ -345,7 +344,8 @@ for sid, cfg, (name, m, cycles, seed) in specs:
     eng.run(streams.make_stream(name, m, cycles, seed=seed))
     seq[sid] = (rec, eng.journal)
 
-mesh = _compat.make_device_mesh((8,), ("fleet",))
+mesh = jax.make_mesh((8,), ("fleet",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 server = FleetServer(mesh=mesh, mesh_axis="fleet")
 recs = {}
 for sid, cfg, (name, m, cycles, seed) in specs:

@@ -219,12 +219,13 @@ def test_solve_shardmap_guards():
     """The neighbour path validates its inputs before touching devices:
     a missing or shape-mismatched halo schedule fails loudly."""
     import jax
-    from repro.core import cls, _compat
+    from repro.core import cls
     obs = np.sort(np.random.default_rng(5).uniform(0, 1, 80))
     prob = cls.local_problem(jax.random.PRNGKey(0), 32, obs)
     dec = dd.decompose_1d(32, dd.uniform_boundaries(1), overlap=0)
     packed = ddkf.pack(prob, dec)
-    mesh = _compat.make_device_mesh((1,), ("sub",))
+    mesh = jax.make_mesh((1,), ("sub",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="halo_exchange"):
         ddkf.solve_shardmap(packed, mesh, comm="neighbour", halo=None)
     other = dd.decompose_1d(32, dd.uniform_boundaries(2), overlap=2)

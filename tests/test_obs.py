@@ -149,6 +149,20 @@ def test_disabled_tracing_overhead_micro_bench():
     assert dt < 1.0, f"disabled tracing cost {dt / n * 1e6:.2f}us/span"
 
 
+def test_jax_profile_raises_when_profiler_fails(monkeypatch):
+    """--profile DIR with a profiler that cannot start fails the run
+    instead of finishing without a profile; no DIR is a no-op."""
+    def broken(logdir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "trace", broken)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with obs_trace.jax_profile("/nonexistent/profile"):
+            pass
+    with obs_trace.jax_profile(None) as got:
+        assert got is None
+
+
 def test_tracing_context_restores_previous():
     tr = obs_trace.Tracer()
     base = obs_trace.get_tracer()

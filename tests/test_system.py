@@ -128,3 +128,18 @@ def test_dryrun_cell_helpers_importable():
     spec = shapes.input_specs(cfg, "train_4k")
     assert set(spec) == {"tokens", "labels", "mask", "frames"}
     assert all(isinstance(s, jax.ShapeDtypeStruct) for s in spec.values())
+
+
+def test_chip_smoke_refuses_a_host_without_tpu():
+    """chip_smoke.py never reports a CPU run as a chip run: with no TPU it
+    exits non-zero, names the platform it found, and prints no ok line."""
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root,
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert "platform 'cpu'" in out.stderr
+    assert '"ok"' not in out.stdout

@@ -28,7 +28,6 @@ on the main thread via a cheap ``dataclasses.replace``.
 """
 from __future__ import annotations
 
-import contextlib
 import copy
 import dataclasses
 import time
@@ -53,17 +52,6 @@ from repro.runtime import chaos as chaos_mod
 from repro.runtime.straggler import StragglerConfig, StragglerMonitor
 from repro.assim import streams as streams_mod
 from repro.assim.metrics import CycleMetrics, Journal, imbalance_ratio
-
-
-@contextlib.contextmanager
-def _phase(phases: dict, name: str, **args):
-    """Time one engine phase into both telemetry sinks: the journal's
-    per-cycle ``phases`` dict (always, via perf_counter) and the active
-    tracer's span timeline (a shared no-op when tracing is off)."""
-    t0 = time.perf_counter()
-    with trace_mod.span(name, **args):
-        yield
-    phases[name] = phases.get(name, 0.0) + (time.perf_counter() - t0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,10 +224,13 @@ class _Prepared:
     rebalance_suppressed: bool = False  # trigger armed but suppressed
                                         # (previous rebalance already
                                         # left these exact loads)
-    phases: dict = dataclasses.field(default_factory=dict)
+    phases: trace_mod.Phases = dataclasses.field(
+        default_factory=trace_mod.Phases)
                                         # host-phase durations (count/
-                                        # dydd/halo/pack/data); _run_cycle
-                                        # adds solve before journalling
+                                        # dydd/halo/pack/pack.*/data) and
+                                        # the compiles each caused;
+                                        # _solve adds solve.*,
+                                        # complete_cycle solve
     comm_edge_bytes_per_cycle: dict = dataclasses.field(
         default_factory=dict)           # "i-j" -> per-cycle endpoint
                                         # bytes (neighbour-path pricing
@@ -494,6 +485,11 @@ class AssimilationEngine:
         ``prepare`` per engine may be in flight at a time (the serving
         layer's packing pool enforces this per stream).  ``window`` tags
         the resulting cycle record with a time-window id."""
+        with trace_mod.span("prepare", cycle=cycle):
+            return self._prepare(cycle, obs, window)
+
+    def _prepare(self, cycle: int, obs: np.ndarray,
+                 window: int) -> _Prepared:
         # Fault injection sits BEFORE any state mutation: a retried
         # prepare after a TransientFault starts from identical rng/
         # domain/truth state, so the retry is bitwise-equivalent to an
@@ -503,15 +499,15 @@ class AssimilationEngine:
         t0 = time.perf_counter()
         cfg = self.cfg
         obs = np.asarray(obs, dtype=np.float64)
-        phases: dict = {}
+        phases = trace_mod.Phases()
 
-        with _phase(phases, "count", cycle=cycle):
+        with trace_mod.phase(phases, "count", cycle=cycle):
             loads_in = self.domain.counts(obs)
             imb_before = imbalance_ratio(loads_in)
             fire = self._should_rebalance(loads_in)
         repartitioned, migrated, rounds = False, 0, 0
         if fire:
-            with _phase(phases, "dydd", cycle=cycle):
+            with trace_mod.phase(phases, "dydd", cycle=cycle):
                 info = self.domain.rebalance(
                     obs, cost_offsets=self._halo_offsets())
             repartitioned = True
@@ -523,7 +519,7 @@ class AssimilationEngine:
         if repartitioned:
             self._last_rebalance_loads = np.asarray(loads).copy()
 
-        with _phase(phases, "halo", cycle=cycle):
+        with trace_mod.phase(phases, "halo", cycle=cycle):
             dec = self._current_dec()
             # Weighted loads: what the overlap-aware schedule balances
             # (the plain counts when halo_weight is 0).
@@ -534,21 +530,27 @@ class AssimilationEngine:
             # prices the neighbour path even when the solve runs
             # allreduce/vmapped.
             halo = dec.halo_exchange
-        with _phase(phases, "pack", cycle=cycle, p=self.p):
-            H1 = cls_mod.observation_operator(
-                self.n, self.domain.obs_positions(obs),
-                block=self.domain.row_size)
-            A = np.concatenate([self._H0, H1], axis=0)
-            r = np.ones((A.shape[0],))
+        with trace_mod.phase(phases, "pack", cycle=cycle, p=self.p):
+            with trace_mod.phase(phases, "pack.h1"):
+                H1 = cls_mod.observation_operator(
+                    self.n, self.domain.obs_positions(obs),
+                    block=self.domain.row_size)
+            with trace_mod.phase(phases, "pack.concat"):
+                A = np.concatenate([self._H0, H1], axis=0)
+                r = np.ones((A.shape[0],))
+            with trace_mod.phase(phases, "pack.roundtrip"):
+                # Through the device and back: A takes the device's dtype
+                # (float32 unless x64 is on).
+                A = np.asarray(jnp.asarray(A))
+            # pack_operator times pack.fill, pack.h2d and pack.factor,
+            # which blocks on the batched factor build (still on the
+            # worker thread under double buffering) so pack_time is
+            # honest.
             packed_op = ddkf_mod.pack_operator(
-                jnp.asarray(A), jnp.asarray(r), dec, mu=cfg.mu,
-                solver_kernel=cfg.solver_kernel)
-            # The batched factor build runs on device; block here (still
-            # on the worker thread under double buffering) so pack_time
-            # is honest.
-            jax.block_until_ready(packed_op.L_loc)
+                A, r, dec, mu=cfg.mu, solver_kernel=cfg.solver_kernel,
+                phases=phases)
 
-        with _phase(phases, "data", cycle=cycle):
+        with trace_mod.phase(phases, "data", cycle=cycle):
             # Truth-driven observation data: the truth random-walks each
             # cycle (deterministic under cfg.seed, independent of any
             # solve result — which is what makes this whole method
@@ -636,11 +638,12 @@ class AssimilationEngine:
         # fault raised here leaves the cycle cleanly retryable.
         if self._chaos is not None:
             self._chaos.check("solve", prep.cycle)
-        packed, background = self.solve_input(prep)
+        with trace_mod.phase(prep.phases, "solve.input", cycle=prep.cycle):
+            packed, background = self.solve_input(prep)
         hist = None
         device_times: list = []
-        with trace_mod.span("solve", cycle=prep.cycle,
-                            solver=cfg.solver) as sp:
+        with trace_mod.phase(prep.phases, "solve.device", cycle=prep.cycle,
+                             solver=cfg.solver) as ph:
             t0 = time.perf_counter()
             if cfg.solver == "shardmap":
                 out = ddkf_mod.solve_shardmap(
@@ -670,7 +673,7 @@ class AssimilationEngine:
                 x = out[0] if cfg.record_residuals else out
                 if cfg.record_residuals:
                     hist = out[1]
-            sp.fence(x)
+            ph.fence(x)
         return x, background, hist, device_times
 
     def _reference_error(self, prep: _Prepared, background: np.ndarray,
@@ -805,17 +808,20 @@ class AssimilationEngine:
     def solve_step(self, step: CycleStep) -> CycleStep:
         """Stage 2 of the cycle state machine: drive a prepared step
         through the device solve (bounded TransientFault retries; wall
-        time measured to analysis-ready)."""
-        t0 = time.perf_counter()
-        x, background, hist, device_times = chaos_mod.retry_transient(
-            lambda: self._solve(step.prep),
-            retries=max(self.cfg.solve_retries, 0),
-            site="solve", cycle=step.prep.cycle)
-        step.analysis = jax.block_until_ready(x)
+        time measured to analysis-ready).  Its journal phases
+        ``solve.input`` and ``solve.device`` are written into the
+        prepared cycle's ``phases``."""
+        with trace_mod.span("solve", cycle=step.prep.cycle):
+            t0 = time.perf_counter()
+            x, background, hist, device_times = chaos_mod.retry_transient(
+                lambda: self._solve(step.prep),
+                retries=max(self.cfg.solve_retries, 0),
+                site="solve", cycle=step.prep.cycle)
+            step.analysis = jax.block_until_ready(x)
+            step.solve_time = time.perf_counter() - t0
         step.background = background
         step.hist = hist
         step.device_times = device_times
-        step.solve_time = time.perf_counter() - t0
         return step
 
     def finish_step(self, step: CycleStep) -> CycleStep:
@@ -891,11 +897,7 @@ class AssimilationEngine:
             m.inc("engine.rebalance.suppressed")
         if prep.migrated:
             m.inc("engine.migrated", prep.migrated)
-        m.observe("engine.imbalance", imbalance_ratio(prep.loads))
-        m.observe("engine.halo_fraction", prep.halo_fraction)
         m.inc("solve.comm_bytes_per_cycle", prep.comm_bytes_per_cycle)
-        if residual_history:
-            m.observe("engine.residual_final", residual_history[-1])
         if flags:
             m.inc("engine.straggler.flags", len(flags))
             m.event("engine.straggler", cycle=prep.cycle, devices=flags,
@@ -922,6 +924,7 @@ class AssimilationEngine:
             loads_weighted=[int(v) for v in prep.loads_weighted],
             rebalance_suppressed=prep.rebalance_suppressed,
             phases=phases,
+            compiles={k: list(v) for k, v in prep.phases.compiles.items()},
             residual_history=residual_history,
             comm_edge_bytes_per_cycle=prep.comm_edge_bytes_per_cycle,
             comm_mvec_bytes_per_cycle=prep.comm_mvec_bytes_per_cycle,

@@ -67,9 +67,17 @@ class CycleMetrics:
     # journals written before it round-trip unchanged).
     phases: dict = dataclasses.field(default_factory=dict)
                                 # per-phase host durations (s): count,
-                                # dydd, halo, pack, data, solve — the
-                                # span timings, journalled even when no
-                                # tracer is installed
+                                # dydd, halo, pack (and its steps
+                                # pack.h1/concat/roundtrip/fill/h2d/
+                                # factor), data, solve (and solve.input,
+                                # solve.device) — the span timings,
+                                # journalled even when no tracer is
+                                # installed
+    compiles: dict = dataclasses.field(default_factory=dict)
+                                # phase -> [count, seconds] of the backend
+                                # compiles the cycle caused, under the
+                                # innermost journal phase open on the
+                                # compiling thread
     residual_history: list = dataclasses.field(default_factory=list)
                                 # per-iteration Schwarz update norms
                                 # ||x^{k+1} - x^k||_F (empty unless
@@ -111,6 +119,8 @@ class CycleMetrics:
         d["loads_before"] = [int(v) for v in self.loads_before]
         d["loads_weighted"] = [int(v) for v in self.loads_weighted]
         d["phases"] = {k: float(v) for k, v in self.phases.items()}
+        d["compiles"] = {k: [int(c), float(t)]
+                         for k, (c, t) in self.compiles.items()}
         d["residual_history"] = [float(v) for v in self.residual_history]
         d["comm_edge_bytes_per_cycle"] = {
             k: float(v) for k, v in self.comm_edge_bytes_per_cycle.items()}
@@ -237,7 +247,7 @@ class Journal:
     # comparisons strip them (everything else in a record is a pure
     # function of stream + seed + config).
     NONDETERMINISTIC_FIELDS = ("pack_time", "solve_time", "cycle_time",
-                               "phases", "device_solve_times",
+                               "phases", "compiles", "device_solve_times",
                                "straggler_flags")
 
     def deterministic_dict(self) -> dict:

@@ -46,6 +46,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import cls as cls_mod
 from repro.core import dd as dd_mod
 from repro.kernels import ops as ops_mod
+from repro.obs import trace as trace_mod
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -252,7 +253,8 @@ def _factor_batched(A_loc: jax.Array, r: jax.Array, diag_add: jax.Array,
 
 def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
                   mu: float = 1.0, gram_mode: str = "auto",
-                  solver_kernel: str = "auto") -> PackedDD:
+                  solver_kernel: str = "auto",
+                  phases: dict | None = None) -> PackedDD:
     """Pack the *operator* part of a decomposed CLS problem.
 
     The host slices the p column blocks into the padded (p, m, w) layout;
@@ -273,62 +275,79 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     (``ops.schwarz_block_for``) and the choice rides along statically in
     the packing's meta fields.
 
+    ``phases`` (the caller's per-cycle dict, optional) receives the
+    seconds of the steps run here, each fenced on its device work:
+    ``pack.fill`` (the padded blocks and index maps), ``pack.h2d`` (their
+    copy to the device) and ``pack.factor`` (the block lookups or
+    autotune and the factor build).  Without it nothing is written and
+    nothing blocks.
+
     The returned ``PackedDD`` carries a zero rhs; pass it through
     :func:`with_rhs` before solving.
     """
     m, n = A.shape
     p = dec.p
-    w = max(1, max(int(np.asarray(c).shape[0]) for c in dec.col_sets))
-
-    # Per-column multiplicity is the decomposition's source of truth: the
-    # halo columns (multiplicity > 1) carry the mu-regularization and the
-    # 1/multiplicity partition-of-unity assembly weight, on any graph.
-    counts = dec.column_multiplicity
-    halo_mu = dec.has_overlap and mu > 0.0
-
-    A_loc = np.zeros((p, m, w), dtype=np.asarray(A).dtype)
-    cols = -np.ones((p, w), dtype=np.int64)
-    mask = np.zeros((p, w), dtype=np.asarray(A).dtype)
-    muov = np.zeros((p, w), dtype=np.asarray(A).dtype)
     A_np = np.asarray(A)
-    for i, c in enumerate(dec.col_sets):
-        c = np.asarray(c)
-        k = c.shape[0]
-        A_loc[i, :, :k] = A_np[:, c]
-        cols[i, :k] = c
-        mask[i, :k] = 1.0
-        if halo_mu:
-            muov[i, :k] = mu * (counts[c] > 1).astype(muov.dtype)
-    A_loc = jnp.asarray(A_loc)
-    r = jnp.asarray(r, A_loc.dtype)
-    # mu on overlap slots; identity on padded slots (mask == 0).  The
-    # gram reduction tile is autotuned host-side (first call per shape,
-    # cached) and handed to the jitted factor build as a static arg.
-    gram_block = ops_mod.gram_block_for((p, m, w), A_loc.dtype,
-                                        mode=gram_mode)
-    L_loc = _factor_batched(A_loc, r, jnp.asarray(muov + (1.0 - mask)),
-                            gram_mode=gram_mode, gram_block=gram_block)
-    solve_kernel = _resolve_solver_kernel(solver_kernel)
-    solve_block = (ops_mod.schwarz_block_for(
-        (p, m, w), A_loc.dtype, mode=_KERNEL_OPS_MODE[solve_kernel])
-        if solve_kernel != "jnp" else None)
-    mult_at = np.maximum(counts, 1)[np.clip(cols, 0, n - 1)]
-    wdiv = mask / mult_at
-    # Precomputed index maps: scatter redirects padding to the dump slot
-    # n, gather clips it to 0 (mask kills the value) — built once here
-    # instead of a where(cols >= 0, ...) membership mask per call.
-    mult_loc = np.where(cols >= 0, mult_at, 1.0)
-    scatter_cols = np.where(cols >= 0, cols, n)
-    gather_cols = np.where(cols >= 0, cols, 0)
-    return PackedDD(A_loc=A_loc, L_loc=L_loc,
-                    cols=jnp.asarray(cols), mask=jnp.asarray(mask),
-                    muov=jnp.asarray(muov), wdiv=jnp.asarray(wdiv),
-                    mult=jnp.asarray(np.maximum(counts, 1)).astype(A.dtype),
-                    mult_loc=jnp.asarray(mult_loc, A_loc.dtype),
-                    scatter_cols=jnp.asarray(scatter_cols),
-                    gather_cols=jnp.asarray(gather_cols),
-                    r=r, b=jnp.zeros((m,), dtype=A_loc.dtype), n=n, p=p,
-                    w=w, solve_kernel=solve_kernel, solve_block=solve_block)
+    with trace_mod.phase(phases, "pack.fill"):
+        w = max(1, max(int(np.asarray(c).shape[0]) for c in dec.col_sets))
+        # Per-column multiplicity is the decomposition's source of truth:
+        # the halo columns (multiplicity > 1) carry the mu-regularization
+        # and the 1/multiplicity partition-of-unity assembly weight, on
+        # any graph.
+        counts = dec.column_multiplicity
+        halo_mu = dec.has_overlap and mu > 0.0
+        A_loc = np.zeros((p, m, w), dtype=A_np.dtype)
+        cols = -np.ones((p, w), dtype=np.int64)
+        mask = np.zeros((p, w), dtype=A_np.dtype)
+        muov = np.zeros((p, w), dtype=A_np.dtype)
+        for i, c in enumerate(dec.col_sets):
+            c = np.asarray(c)
+            k = c.shape[0]
+            A_loc[i, :, :k] = A_np[:, c]
+            cols[i, :k] = c
+            mask[i, :k] = 1.0
+            if halo_mu:
+                muov[i, :k] = mu * (counts[c] > 1).astype(muov.dtype)
+        # mu on overlap slots; identity on padded slots (mask == 0).
+        diag_add = muov + (1.0 - mask)
+        mult_at = np.maximum(counts, 1)[np.clip(cols, 0, n - 1)]
+        wdiv = mask / mult_at
+        # Precomputed index maps: scatter redirects padding to the dump
+        # slot n, gather clips it to 0 (mask kills the value) — built once
+        # here instead of a where(cols >= 0, ...) membership mask per call.
+        mult_loc = np.where(cols >= 0, mult_at, 1.0)
+        scatter_cols = np.where(cols >= 0, cols, n)
+        gather_cols = np.where(cols >= 0, cols, 0)
+
+    with trace_mod.phase(phases, "pack.h2d") as ph:
+        A_loc = ph.fence(jnp.asarray(A_loc))
+        r = jnp.asarray(r, A_loc.dtype)
+        diag_add = jnp.asarray(diag_add)
+        dev = dict(cols=jnp.asarray(cols), mask=jnp.asarray(mask),
+                   muov=jnp.asarray(muov), wdiv=jnp.asarray(wdiv),
+                   mult=jnp.asarray(np.maximum(counts, 1)).astype(A.dtype),
+                   mult_loc=jnp.asarray(mult_loc, A_loc.dtype),
+                   scatter_cols=jnp.asarray(scatter_cols),
+                   gather_cols=jnp.asarray(gather_cols),
+                   b=jnp.zeros((m,), dtype=A_loc.dtype))
+
+    with trace_mod.phase(phases, "pack.factor") as ph:
+        # Both block sizes are autotuned host-side (first call per shape,
+        # cached): the gram's is handed to the jitted factor build as a
+        # static arg, the solve's is timed while the build runs and rides
+        # in the packing's meta fields.
+        gram_block = ops_mod.gram_block_for((p, m, w), A_loc.dtype,
+                                            mode=gram_mode)
+        L_loc = ph.fence(_factor_batched(A_loc, r, diag_add,
+                                         gram_mode=gram_mode,
+                                         gram_block=gram_block))
+        solve_kernel = _resolve_solver_kernel(solver_kernel)
+        solve_block = (ops_mod.schwarz_block_for(
+            (p, m, w), A_loc.dtype, mode=_KERNEL_OPS_MODE[solve_kernel])
+            if solve_kernel != "jnp" else None)
+    return PackedDD(A_loc=A_loc, L_loc=L_loc, r=r, n=n, p=p, w=w,
+                    solve_kernel=solve_kernel, solve_block=solve_block,
+                    **dev)
 
 
 def with_rhs(packed: PackedDD, b: jax.Array) -> PackedDD:

@@ -151,9 +151,7 @@ def _solve_laplacian_cg(edges_arr: np.ndarray, deg: np.ndarray,
     q = r.copy()
     rs = r @ r
     maxiter = maxiter or 4 * p
-    cg_hist = meters_mod.get_meters().series["dydd.cg_residual"]
     for _ in range(maxiter):
-        cg_hist.append(float(np.sqrt(rs)))
         if rs < tol * tol * max(b @ b, 1e-30):
             break
         Lq = apply_L(q)

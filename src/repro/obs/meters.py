@@ -11,9 +11,9 @@ Four instrument kinds:
 
   * **counter** — monotonically accumulated totals
     (``inc("engine.rebalance.fired")``);
-  * **gauge**   — last-written values (``gauge("engine.imbalance", x)``);
+  * **gauge**   — last-written values (``gauge(name, x)``);
   * **series**  — append-only float lists
-    (``observe("dydd.cg_residual", r)`` — per-iteration histories);
+    (``observe("engine.snapshot_time", s)``);
   * **event**   — timestamped structured payloads
     (``event("gram.autotune", shape=..., block_m=...)`` — the autotune
     decisions, halo-schedule builds, rebalance triggers/suppressions).
@@ -25,12 +25,11 @@ Meter name taxonomy (dotted, subsystem-first) — the full list lives in
 ``src/repro/assim/README.md`` §Observability:
 
     engine.cycles, engine.rebalance.fired, engine.rebalance.suppressed,
-    engine.migrated, engine.imbalance, engine.halo_fraction,
-    engine.residual_final, engine.straggler.flags,
+    engine.migrated, engine.straggler.flags, engine.snapshot_time,
     solve.comm_bytes_per_cycle,
     halo.builds, halo.edges, halo.rounds,
-    dydd.schedule_rounds, dydd.scheduled_movement, dydd.cg_residual,
-    gram.autotune
+    dydd.schedule_rounds, dydd.scheduled_movement,
+    gram.autotune, schwarz.autotune
 """
 from __future__ import annotations
 

@@ -37,6 +37,23 @@ Spans with an explicit ``track=`` land on a named row instead of the
 thread's — :meth:`Tracer.emit` uses this to attach per-device rows
 ("device 0" ... "device p-1") from timestamps observed after the fact
 (per-shard ready times of a sharded solve).
+
+**On the profiler's clock.**  Every span also enters a
+``jax.profiler.TraceAnnotation("repro.<name>")``, so a running
+``jax.profiler`` trace holds the program's phases on the device
+trace's clock, on the thread that ran them, with or without a
+:class:`Tracer`.  With neither a Tracer nor a profiler running,
+``span`` is still the shared no-op.  Retroactive :func:`emit` rows
+stay Tracer-only.
+
+**Journal phases.**  :func:`phase` times one engine phase into a
+per-cycle ``phases`` dict (always) and opens a span (both sinks above);
+phases nest (``pack`` > ``pack.fill``).  Once the first :class:`Phases`
+is made, a process-wide ``jax.monitoring`` listener counts each backend
+compile into the ``compiles`` of the innermost open phase on the
+compiling thread whose dict is a :class:`Phases`.
+
+jax is imported on first use, not with this module.
 """
 from __future__ import annotations
 
@@ -45,6 +62,19 @@ import json
 import threading
 import time
 from typing import Optional
+
+PROFILER_PREFIX = "repro."
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_ANNOTATION = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first span."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
 
 
 # ---------------------------------------------------------------------------
@@ -72,12 +102,34 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(_NullSpan):
+    """A span seen only by a running ``jax.profiler`` trace (no Tracer
+    installed): enters ``TraceAnnotation("repro.<name>")``, records
+    nothing in memory and never fences."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _annotation()(PROFILER_PREFIX + name)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+
 class NullTracer:
-    """The inactive tracer: every span is the shared no-op instance."""
+    """The inactive tracer: every span is the shared no-op instance, or
+    a profiler annotation while a ``jax.profiler`` trace runs."""
 
     enabled = False
 
     def span(self, name: str, track: Optional[str] = None, **args):
+        if _annotation().is_enabled():
+            return _ProfilerSpan(name)
         return _NULL_SPAN
 
     def emit(self, name: str, t0: float, dur: float,
@@ -95,7 +147,8 @@ NULL_TRACER = NullTracer()
 class _Span:
     """One live span; records a complete ("X") event on exit."""
 
-    __slots__ = ("_tracer", "name", "track", "args", "_t0", "_fence")
+    __slots__ = ("_tracer", "name", "track", "args", "_t0", "_fence",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: Optional[str],
                  args: dict):
@@ -105,6 +158,7 @@ class _Span:
         self.args = args
         self._t0 = 0.0
         self._fence = None
+        self._ann = _annotation()(PROFILER_PREFIX + name)
 
     def __enter__(self):
         tracer = self._tracer
@@ -115,6 +169,7 @@ class _Span:
         if stack:
             self.args.setdefault("parent", stack[-1].name)
         stack.append(self)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -123,6 +178,7 @@ class _Span:
             _block(self._fence)
             self._fence = None
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -306,6 +362,104 @@ def span(name: str, track: Optional[str] = None, **args):
 def emit(name: str, t0: float, dur: float, track: Optional[str] = None,
          **args) -> None:
     _ACTIVE.emit(name, t0, dur, track=track, **args)
+
+
+# ---------------------------------------------------------------------------
+# Journal phases and the compiles they cause.
+# ---------------------------------------------------------------------------
+
+class Phases(dict):
+    """One cycle's phase seconds, ``{phase: s}``, with ``compiles``:
+    ``{phase: [count, s]}`` of the backend compiles each phase caused.
+    Making the first one registers the compile listener."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.compiles: dict = {}
+        _listen_for_compiles()
+
+
+# The innermost open phase on each thread whose dict is a Phases: where
+# the listener puts a compile.  The Tracer's span stack cannot serve, as
+# it exists only while a Tracer is installed; the journal records always.
+_OPEN = threading.local()
+
+
+class _Phase:
+    """One open journal phase; see :func:`phase`."""
+
+    __slots__ = ("phases", "name", "_span", "_t0", "_fence", "_outer")
+
+    def __init__(self, phases: Optional[dict], name: str, args: dict):
+        self.phases = phases
+        self.name = name
+        self._span = span(name, **args)
+        self._t0 = 0.0
+        self._fence = None
+        self._outer = None
+
+    def __enter__(self):
+        self._span.__enter__()
+        if isinstance(self.phases, Phases):
+            self._outer = getattr(_OPEN, "phase", None)
+            _OPEN.phase = self
+        self._t0 = time.perf_counter()
+        return self
+
+    def fence(self, value):
+        """Register a device value to block on before the phase closes
+        (when it has a ``phases`` dict), so its time includes the device
+        work that produced the value.  Returns the value unchanged."""
+        self._fence = value
+        return value
+
+    def __exit__(self, *exc):
+        if exc[0] is None and self.phases is not None:
+            # A phase that raised records nothing.
+            if self._fence is not None:
+                _block(self._fence)
+            self.phases[self.name] = (self.phases.get(self.name, 0.0)
+                                      + time.perf_counter() - self._t0)
+        self._fence = None
+        if isinstance(self.phases, Phases):
+            _OPEN.phase = self._outer
+            self._outer = None
+        self._span.__exit__(*exc)
+        return False
+
+
+def phase(phases: Optional[dict], name: str, **args) -> _Phase:
+    """Time one phase into both telemetry sinks: ``phases[name]``
+    (seconds, accumulated; nothing when ``phases`` is None) and a
+    :func:`span` (the Tracer, when installed, and the profiler's
+    ``repro.<name>``, while a trace runs).  ``with phase(...) as ph:
+    ...; ph.fence(x)`` includes the device work behind ``x`` when
+    ``phases`` is given, and blocks on nothing when it is None."""
+    return _Phase(phases, name, args)
+
+
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
+
+
+def _listen_for_compiles() -> None:
+    global _LISTENING
+    if _LISTENING:
+        return
+    with _LISTEN_LOCK:
+        if not _LISTENING:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile)
+            _LISTENING = True
+
+
+def _on_compile(event: str, seconds: float, **kw) -> None:
+    ph = getattr(_OPEN, "phase", None)
+    if event != COMPILE_EVENT or ph is None:
+        return
+    count, total = ph.phases.compiles.get(ph.name, (0, 0.0))
+    ph.phases.compiles[ph.name] = [count + 1, total + float(seconds)]
 
 
 @contextlib.contextmanager

@@ -6,7 +6,11 @@ residual-history monotonicity on a converging solve, comm-matrix totals
 against the journalled per-cycle bytes, journal round-trips with the new
 fields, and the straggler monitor wired through the engine cycle loop.
 """
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -371,3 +375,188 @@ def test_engine_disabled_tracing_by_default(fresh_meters):
                              record_residuals=False)
     assert all(r.phases["solve"] > 0 for r in journal.records)
     assert all(r.residual_history == [] for r in journal.records)
+
+
+# ---------------------------------------------------------------------------
+# Journal phases: pack and solve in timed steps, compiles per phase, the
+# profiler's timeline.
+# ---------------------------------------------------------------------------
+
+PACK_STEPS = ("pack.h1", "pack.concat", "pack.roundtrip", "pack.fill",
+              "pack.h2d", "pack.factor")
+SOLVE_STEPS = ("solve.input", "solve.device")
+
+
+def test_phase_writes_nested_steps_and_accumulates():
+    phases = obs_trace.Phases()
+    with obs_trace.phase(phases, "outer"):
+        with obs_trace.phase(phases, "outer.step"):
+            time.sleep(0.002)
+        with obs_trace.phase(phases, "outer.step"):
+            time.sleep(0.002)
+    assert phases["outer.step"] >= 0.004
+    assert phases["outer"] >= phases["outer.step"]
+    assert phases.compiles == {}
+    with obs_trace.phase(None, "nowhere") as ph:   # writes nothing
+        assert ph.fence(7) == 7
+    assert "nowhere" not in phases
+
+
+def test_engine_pack_and_solve_steps_cover_their_phases():
+    """Every cycle's pack.* steps cover >= 95% of pack and its solve.*
+    steps >= 95% of solve; pack stays the total.  (Cycles of a few ms
+    each, one thread: what lies between the steps is the instrumentation
+    and the packing's return, tens of us.)"""
+    eng = AssimilationEngine(EngineConfig(n=128, p=4, iters=200, overlap=1,
+                                          double_buffer=False))
+    journal = eng.run_scenario("drifting_swarm", m=300, cycles=4)
+    for rec in journal.records:
+        ph = rec.phases
+        assert set(PACK_STEPS) <= set(ph) and set(SOLVE_STEPS) <= set(ph)
+        pack = sum(ph[k] for k in PACK_STEPS)
+        solve = sum(ph[k] for k in SOLVE_STEPS)
+        assert 0.95 * ph["pack"] <= pack <= ph["pack"]
+        assert 0.95 * ph["solve"] <= solve <= ph["solve"]
+
+
+def test_engine_analyses_bitwise_with_and_without_tracer():
+    runs = []
+    for tracer in (None, obs_trace.Tracer()):
+        xs = []
+        eng = AssimilationEngine(EngineConfig(
+            n=48, p=4, iters=60, overlap=1, comm="neighbour",
+            record_residuals=True, double_buffer=True))
+        eng.on_analysis = lambda c, x: xs.append(np.asarray(x))
+        with obs_trace.tracing(tracer):
+            eng.run_scenario("drifting_swarm", m=160, cycles=3)
+        runs.append(np.stack(xs))
+    np.testing.assert_array_equal(runs[0], runs[1])
+
+
+def test_compiles_are_journalled_under_the_phase_that_caused_them(
+        fresh_meters):
+    """A cycle at a width this process has not compiled records its
+    compiles under pack.factor and solve.device; a repeat cycle on the
+    same network records none."""
+    obs = np.sort(np.random.default_rng(5).uniform(0.05, 0.95, 97))
+    eng = AssimilationEngine(EngineConfig(n=44, p=3, iters=20,
+                                          rebalance=False))
+    journal = eng.run([obs, obs])
+    first, repeat = journal.records
+    assert first.compiles["pack.factor"][0] >= 1
+    assert first.compiles["solve.device"][0] >= 1
+    assert all(c >= 1 and s > 0 for c, s in first.compiles.values())
+    assert repeat.compiles == {}
+    # The per-cycle series the journal already holds are no meters.
+    assert not {"engine.imbalance", "engine.halo_fraction",
+                "engine.residual_final", "dydd.cg_residual"} & set(
+                    fresh_meters.series)
+    doc = CycleMetrics.from_dict(json.loads(json.dumps(first.to_dict())))
+    assert doc.compiles == first.compiles
+
+
+def _host_lines(logdir):
+    """[[event names] per line] of the host plane of the newest profile
+    under ``logdir``."""
+    path = max(glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    data = jax.profiler.ProfileData.from_file(path)
+    host, = [pl for pl in data.planes if pl.name == "/host:CPU"]
+    return [[e.name for e in line.events] for line in host.lines]
+
+
+def test_profiler_trace_holds_engine_phases_without_a_tracer(tmp_path):
+    """With no Tracer and no wrapper, a jax.profiler trace holds the
+    engine's phases as repro.* host events on the thread that ran them:
+    prepare and the pack steps on the packing worker's line, the solve
+    and its steps on the main thread's."""
+    assert isinstance(obs_trace.get_tracer(), obs_trace.NullTracer)
+    eng = AssimilationEngine(EngineConfig(n=48, p=4, iters=30,
+                                          double_buffer=True))
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run_scenario("drifting_swarm", m=160, cycles=2)
+    lines = [set(n for n in names if n.startswith("repro."))
+             for names in _host_lines(str(tmp_path))]
+    worker = [ln for ln in lines if "repro.prepare" in ln]
+    main = [ln for ln in lines if "repro.solve" in ln]
+    assert len(worker) == 1 and len(main) == 1 and worker != main
+    assert {"repro.prepare", "repro.count", "repro.pack", "repro.data"} | {
+        "repro." + k for k in PACK_STEPS} <= worker[0]
+    assert {"repro.solve"} | {"repro." + k for k in SOLVE_STEPS} <= main[0]
+    # Outside a trace the disabled path is the shared no-op again.
+    assert obs_trace.span("a") is obs_trace.span("b")
+
+
+def _fresh_compile():
+    """Compile a function jax has not seen before; returns its value."""
+    return jax.jit(lambda v: v * 3.0 + 1.0)(np.arange(3.0))
+
+
+def test_compiles_go_to_the_innermost_journal_phase_only():
+    """A compile lands under the innermost open phase whose dict is a
+    Phases, passing over a phase without a dict; under a plain dict or
+    no phase at all it is journalled nowhere."""
+    phases = obs_trace.Phases()
+    plain: dict = {}
+    with obs_trace.phase(phases, "outer"):
+        with obs_trace.phase(None, "no-dict"):
+            _fresh_compile()
+    with obs_trace.phase(plain, "plain"):
+        _fresh_compile()
+    _fresh_compile()
+    assert set(phases.compiles) == {"outer"}
+    count, seconds = phases.compiles["outer"]
+    assert count >= 1 and seconds > 0
+    assert set(plain) == {"plain"} and not hasattr(plain, "compiles")
+
+
+def test_phase_without_a_dict_never_fences(monkeypatch):
+    """Only a phase that records blocks on its fence, Tracer or not."""
+    blocked = []
+    monkeypatch.setattr(obs_trace, "_block", blocked.append)
+    with obs_trace.tracing(obs_trace.Tracer()):
+        with obs_trace.phase(None, "unrecorded") as ph:
+            ph.fence("a")
+        phases = obs_trace.Phases()
+        with obs_trace.phase(phases, "recorded") as ph:
+            ph.fence("b")
+    with obs_trace.phase(None, "untraced") as ph:
+        ph.fence("c")
+    assert blocked == ["b"] and set(phases) == {"recorded"}
+
+
+def test_importing_obs_loads_no_jax_and_registers_no_listener():
+    """repro.obs stays importable from every layer without jax; the
+    compile listener is registered by the first Phases."""
+    code = ("import sys; import repro.obs as o; "
+            "assert 'jax' not in sys.modules, 'jax'; "
+            "assert not o.trace._LISTENING; "
+            "o.Phases(); assert o.trace._LISTENING; "
+            "assert 'jax' in sys.modules")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + [v for v in [os.environ.get("PYTHONPATH")] if v]))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+@pytest.mark.parametrize("overlap", [0, 1])
+def test_pack_operator_steps_leave_the_packing_unchanged(overlap):
+    """pack_operator with a phases dict writes its three steps and packs
+    the same arrays, bit for bit, as without one (which writes
+    nothing)."""
+    rng = np.random.default_rng(7)
+    obs = np.sort(rng.beta(2, 5, 150))
+    prob = cls.local_problem(jax.random.PRNGKey(0), 48, obs)
+    dec = dd.decompose_1d(48, dd.uniform_boundaries(4), overlap=overlap)
+    A, _, r = prob.stacked()
+    phases = obs_trace.Phases()
+    timed = ddkf.pack_operator(A, r, dec, phases=phases)
+    plain = ddkf.pack_operator(A, r, dec)
+    assert set(phases) == {"pack.fill", "pack.h2d", "pack.factor"}
+    assert all(v > 0 for v in phases.values())
+    for f in ("A_loc", "L_loc", "cols", "mask", "muov", "wdiv", "mult",
+              "mult_loc", "scatter_cols", "gather_cols", "r", "b"):
+        np.testing.assert_array_equal(np.asarray(getattr(timed, f)),
+                                      np.asarray(getattr(plain, f)))

@@ -10,9 +10,9 @@ The engine consumes an observation stream cycle by cycle and, per cycle:
      boundary migration — ``dydd_1d`` on an :class:`Interval1D`,
      ``dydd_2d``'s per-axis passes on a :class:`ShelfTiling2D`);
   2. decomposes the state index set on the (possibly moved) boundaries and
-     packs the local operator blocks — host-side slicing plus the batched
-     device-side normal-matrix/Cholesky build (``ddkf.pack_operator``,
-     ``kernels.ops.gram``);
+     packs the local operator blocks — the device-side gather of the
+     padded blocks from ``A = [H0; H1]`` plus the batched normal-matrix/
+     Cholesky build (``ddkf.pack_operator``, ``kernels.ops.gram``);
   3. injects the cycle's right-hand side (background carried forward from
      the previous analysis + fresh observation data) and runs the sharded
      DD-KF solve (``ddkf.solve_vmapped`` / ``solve_shardmap``);
@@ -340,6 +340,7 @@ class AssimilationEngine:
         self.journal = Journal(meta=self.domain.describe())
         self.analysis: Optional[jax.Array] = None
         self._H0 = cls_mod.state_operator(self.n, smooth=config.smooth)
+        self._H0_dev: Optional[jax.Array] = None  # see _H0_device
         self._rng = np.random.default_rng(config.seed)
         self._truth = self._rng.normal(size=self.n)
         self._streak = 0  # consecutive over-threshold cycles
@@ -472,6 +473,13 @@ class AssimilationEngine:
             return None
         return self.cfg.halo_weight * self._current_dec().halo_sizes
 
+    def _H0_device(self) -> jax.Array:
+        """H0 on the device, in the device's dtype: copied once, on the
+        first prepare, since H0 never changes."""
+        if self._H0_dev is None:
+            self._H0_dev = jnp.asarray(self._H0)
+        return self._H0_dev
+
     def prepare(self, cycle: int, obs: np.ndarray,
                 window: int = -1) -> _Prepared:
         """Host-side work for one cycle: DyDD decision, repartition,
@@ -535,17 +543,18 @@ class AssimilationEngine:
                 H1 = cls_mod.observation_operator(
                     self.n, self.domain.obs_positions(obs),
                     block=self.domain.row_size)
-            with trace_mod.phase(phases, "pack.concat"):
-                A = np.concatenate([self._H0, H1], axis=0)
+            with trace_mod.phase(phases, "pack.roundtrip") as ph:
+                # H1, the only part of A that changes from cycle to
+                # cycle, crosses to the device one way, in the device's
+                # dtype (float32 unless x64 is on).
+                H1_dev = ph.fence(jnp.asarray(H1))
+            with trace_mod.phase(phases, "pack.concat") as ph:
+                A = ph.fence(jnp.concatenate([self._H0_device(), H1_dev]))
                 r = np.ones((A.shape[0],))
-            with trace_mod.phase(phases, "pack.roundtrip"):
-                # Through the device and back: A takes the device's dtype
-                # (float32 unless x64 is on).
-                A = np.asarray(jnp.asarray(A))
-            # pack_operator times pack.fill, pack.h2d and pack.factor,
-            # which blocks on the batched factor build (still on the
-            # worker thread under double buffering) so pack_time is
-            # honest.
+            # pack_operator gathers the local blocks from the device A
+            # and times pack.fill, pack.h2d and pack.factor, which blocks
+            # on the batched factor build (still on the worker thread
+            # under double buffering) so pack_time is honest.
             packed_op = ddkf_mod.pack_operator(
                 A, r, dec, mu=cfg.mu, solver_kernel=cfg.solver_kernel,
                 phases=phases)

@@ -251,20 +251,35 @@ def _factor_batched(A_loc: jax.Array, r: jax.Array, diag_add: jax.Array,
     return jax.vmap(jnp.linalg.cholesky)(N)
 
 
+@jax.jit
+def _gather_blocks(A: jax.Array, gather_cols: jax.Array,
+                   cols: jax.Array) -> jax.Array:
+    """The padded local blocks ``A_loc[i, :, j] = A[:, cols[i, j]]``,
+    gathered on the device: exact +0.0 on padded slots (``cols == -1``),
+    the columns copied, so the result equals a host fill bit for bit.
+    Gathers rows of ``A.T``, whole rows along the lanes, and turns the
+    (p, w, m) result to (p, m, w)."""
+    rows = jnp.take(A.T, gather_cols, axis=0, mode="clip")
+    return jnp.where((cols >= 0)[:, None, :], jnp.swapaxes(rows, 1, 2), 0)
+
+
 def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
                   mu: float = 1.0, gram_mode: str = "auto",
                   solver_kernel: str = "auto",
                   phases: dict | None = None) -> PackedDD:
     """Pack the *operator* part of a decomposed CLS problem.
 
-    The host slices the p column blocks into the padded (p, m, w) layout;
-    the p local normal matrices N_i = A_i^T diag(r) A_i and their Cholesky
-    factors are then built *on device* in one batched shot
-    (:func:`_factor_batched`: ``kernels.ops.gram`` + ``vmap(cholesky)``)
-    instead of a per-subdomain ``np.linalg.cholesky`` Python loop.  The
-    packing depends only on (A, r, dec), not on the data vector b, so the
-    streaming engine runs it for cycle t+1 while the device is solving
-    cycle t, then injects the cycle's rhs with :func:`with_rhs` (a cheap
+    ``A`` (m, n) may be a numpy or a device array; it is used on the
+    device and never copied back to the host.  The host builds only the
+    small (p, w) index maps of the decomposition; the p column blocks
+    are gathered from ``A`` into the padded (p, m, w) layout on the
+    device (:func:`_gather_blocks`), and the p local normal matrices
+    N_i = A_i^T diag(r) A_i and their Cholesky factors are then built
+    on the device in one batched shot (:func:`_factor_batched`:
+    ``kernels.ops.gram`` + ``vmap(cholesky)``).  The packing depends
+    only on (A, r, dec), not on the data vector b, so the streaming
+    engine runs it for cycle t+1 while the device is solving cycle t,
+    then injects the cycle's rhs with :func:`with_rhs` (a cheap
     ``dataclasses.replace``).
 
     ``gram_mode`` selects the kernel path ("auto": Pallas on TPU, jnp
@@ -277,18 +292,20 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
 
     ``phases`` (the caller's per-cycle dict, optional) receives the
     seconds of the steps run here, each fenced on its device work:
-    ``pack.fill`` (the padded blocks and index maps), ``pack.h2d`` (their
-    copy to the device) and ``pack.factor`` (the block lookups or
+    ``pack.fill`` (the index maps and the device gather of the padded
+    blocks), ``pack.h2d`` (the copy of the other maps, ``r`` and the
+    diagonal to the device) and ``pack.factor`` (the block lookups or
     autotune and the factor build).  Without it nothing is written and
     nothing blocks.
 
     The returned ``PackedDD`` carries a zero rhs; pass it through
     :func:`with_rhs` before solving.
     """
+    A = jnp.asarray(A)
     m, n = A.shape
     p = dec.p
-    A_np = np.asarray(A)
-    with trace_mod.phase(phases, "pack.fill"):
+    dtype = np.dtype(A.dtype)
+    with trace_mod.phase(phases, "pack.fill") as ph:
         w = max(1, max(int(np.asarray(c).shape[0]) for c in dec.col_sets))
         # Per-column multiplicity is the decomposition's source of truth:
         # the halo columns (multiplicity > 1) carry the mu-regularization
@@ -296,14 +313,12 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
         # any graph.
         counts = dec.column_multiplicity
         halo_mu = dec.has_overlap and mu > 0.0
-        A_loc = np.zeros((p, m, w), dtype=A_np.dtype)
         cols = -np.ones((p, w), dtype=np.int64)
-        mask = np.zeros((p, w), dtype=A_np.dtype)
-        muov = np.zeros((p, w), dtype=A_np.dtype)
+        mask = np.zeros((p, w), dtype=dtype)
+        muov = np.zeros((p, w), dtype=dtype)
         for i, c in enumerate(dec.col_sets):
             c = np.asarray(c)
             k = c.shape[0]
-            A_loc[i, :, :k] = A_np[:, c]
             cols[i, :k] = c
             mask[i, :k] = 1.0
             if halo_mu:
@@ -317,19 +332,21 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
         # here instead of a where(cols >= 0, ...) membership mask per call.
         mult_loc = np.where(cols >= 0, mult_at, 1.0)
         scatter_cols = np.where(cols >= 0, cols, n)
-        gather_cols = np.where(cols >= 0, cols, 0)
+        gather_cols = jnp.asarray(np.where(cols >= 0, cols, 0))
+        cols = jnp.asarray(cols)
+        A_loc = ph.fence(_gather_blocks(A, gather_cols, cols))
 
     with trace_mod.phase(phases, "pack.h2d") as ph:
-        A_loc = ph.fence(jnp.asarray(A_loc))
         r = jnp.asarray(r, A_loc.dtype)
         diag_add = jnp.asarray(diag_add)
-        dev = dict(cols=jnp.asarray(cols), mask=jnp.asarray(mask),
+        dev = dict(cols=cols, mask=jnp.asarray(mask),
                    muov=jnp.asarray(muov), wdiv=jnp.asarray(wdiv),
                    mult=jnp.asarray(np.maximum(counts, 1)).astype(A.dtype),
                    mult_loc=jnp.asarray(mult_loc, A_loc.dtype),
                    scatter_cols=jnp.asarray(scatter_cols),
-                   gather_cols=jnp.asarray(gather_cols),
+                   gather_cols=gather_cols,
                    b=jnp.zeros((m,), dtype=A_loc.dtype))
+        ph.fence((r, diag_add, dev))
 
     with trace_mod.phase(phases, "pack.factor") as ph:
         # Both block sizes are autotuned host-side (first call per shape,

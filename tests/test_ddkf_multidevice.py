@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import cls, dd, ddkf, dydd
+from repro.core.domain import ShelfTiling2D
 
 SCRIPT = r"""
 import jax
@@ -320,6 +321,80 @@ def _pack_factors_numpy(A, r, dec, mu):
         N[pad, pad] = 1.0
         L_ref[i] = np.linalg.cholesky(N)
     return L_ref
+
+
+def _fill_blocks_numpy(A, dec):
+    """The padded (p, m, w) local blocks filled on the host, column block
+    by column block, with +0.0 in the padded slots."""
+    A = np.asarray(A)
+    w = max(int(np.asarray(c).shape[0]) for c in dec.col_sets)
+    A_loc = np.zeros((dec.p, A.shape[0], w), dtype=A.dtype)
+    for i, c in enumerate(dec.col_sets):
+        c = np.asarray(c)
+        A_loc[i, :, :c.shape[0]] = A[:, c]
+    return A_loc
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.dtype(f"u{x.dtype.itemsize}"))
+
+
+_BLOCK_DECS = {
+    "chain_overlap0": lambda: dd.decompose_1d(
+        40, dydd.dydd_1d(np.random.default_rng(2).beta(2, 5, 120),
+                         5).boundaries, overlap=0),
+    "chain_overlap1": lambda: dd.decompose_1d(
+        40, dd.uniform_boundaries(4), overlap=1),
+    "shelf_2d": lambda: ShelfTiling2D(8, 6, 2, 3).decomposition(overlap=1),
+}
+
+
+@pytest.mark.parametrize("as_device", [False, True],
+                         ids=["numpy_A", "device_A"])
+@pytest.mark.parametrize("dec_name", sorted(_BLOCK_DECS))
+def test_pack_operator_gathers_the_host_fill_bit_for_bit(
+        monkeypatch, dec_name, as_device):
+    """The device-built A_loc equals a numpy fill bit for bit, signed
+    zeros included, with +0.0 in every padded slot; a device A is never
+    read back to the host."""
+    from jax._src.array import ArrayImpl
+
+    dec = _BLOCK_DECS[dec_name]()
+    n = dec.n
+    if dec_name == "shelf_2d":   # raster-ordered cells: gaps in a column set
+        assert any(np.any(np.diff(np.asarray(c)) > 1) for c in dec.col_sets)
+    rng = np.random.default_rng(11)
+    A_np = rng.normal(size=(3 * n, n)).astype(np.float32)
+    A_np[rng.random(A_np.shape) < 0.2] = -0.0
+    r = np.ones((A_np.shape[0],))
+    expect = _fill_blocks_numpy(A_np, dec)
+
+    A = jnp.asarray(A_np) if as_device else A_np
+    read_back = []
+    if as_device:
+        # A CPU "device" array reads back with no transfer, so the guard
+        # alone cannot see it there: every host read of A is recorded.
+        def spy(read):
+            return lambda x, *a, **kw: (read_back.append(x is A)
+                                        or read(x, *a, **kw))
+
+        value = ArrayImpl._value
+        monkeypatch.setattr(ArrayImpl, "_value", property(spy(value.fget)))
+        monkeypatch.setattr(np, "asarray", spy(np.asarray))
+        monkeypatch.setattr(np, "array", spy(np.array))
+    with jax.transfer_guard_device_to_host("disallow"):
+        packed = ddkf.pack_operator(A, r, dec)
+        jax.block_until_ready(packed)
+    monkeypatch.undo()
+    assert not any(read_back)
+
+    got = np.asarray(packed.A_loc)
+    assert got.dtype == np.float32 and got.shape == expect.shape
+    np.testing.assert_array_equal(_bits(got), _bits(expect))
+    pad = np.asarray(packed.mask) == 0
+    assert pad.any()
+    assert not np.signbit(got.transpose(0, 2, 1)[pad]).any()
 
 
 @pytest.mark.parametrize("overlap,mu", [(0, 1.0), (2, 0.7)])

@@ -419,6 +419,26 @@ def test_engine_pack_and_solve_steps_cover_their_phases():
         assert 0.95 * ph["solve"] <= solve <= ph["solve"]
 
 
+def test_engine_writes_six_pack_steps_and_copies_h0_once():
+    """Each cycle of a run writes all six pack.* steps, summing to within
+    1% of pack; the engine copies H0 to the device once, on the first
+    prepare, and every later cycle packs from that copy."""
+    eng = AssimilationEngine(EngineConfig(n=512, p=4, iters=20, overlap=1,
+                                          track_reference=False))
+    assert eng._H0_dev is None
+    h0s = []
+    eng.on_analysis = lambda c, x: h0s.append(eng._H0_dev)
+    journal = eng.run_scenario("drifting_swarm", m=1200, cycles=4)
+    assert len(h0s) == 4 and h0s[0] is not None
+    assert all(h is h0s[0] for h in h0s)
+    np.testing.assert_array_equal(np.asarray(h0s[0]), eng._H0)
+    for rec in journal.records:
+        ph = rec.phases
+        assert set(PACK_STEPS) <= set(ph)
+        pack = sum(ph[k] for k in PACK_STEPS)
+        assert 0.99 * ph["pack"] <= pack <= ph["pack"]
+
+
 def test_engine_analyses_bitwise_with_and_without_tracer():
     runs = []
     for tracer in (None, obs_trace.Tracer()):

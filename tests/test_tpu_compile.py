@@ -127,6 +127,22 @@ def test_ops_pads_unaligned_width(one_chip, kernel):
     assert _kernels(lowered.compile()) == 1
 
 
+def test_block_gather_compiles_at_example4_width(one_chip):
+    """The device gather of the padded local blocks at w = 1070, the
+    width DyDD gives Example 4 on the Beta(2, 5) network: one gather,
+    the (p, m, w) blocks out, and at most one more copy of them in
+    temporaries."""
+    w, i32 = 1070, jnp.int32
+    lowered = ddkf._gather_blocks.lower(
+        _sds(one_chip, M, N), _sds(one_chip, P8, w, dtype=i32),
+        _sds(one_chip, P8, w, dtype=i32))
+    assert lowered.out_info.shape == (P8, M, w)
+    compiled = lowered.compile()
+    assert " gather(" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= 2 * mem.output_size_in_bytes
+
+
 @pytest.mark.parametrize("comm", ["allreduce", "neighbour"])
 def test_solve_shardmap_compiles_on_4_chips(topo, monkeypatch, comm):
     """The jitted sharded solve at Example 4's p = 4, overlap-1 shapes on

@@ -70,9 +70,12 @@ class EngineConfig:
     a leading axis of one device; ``solver="shardmap"`` runs one device
     per subdomain on a mesh shaped like the domain's processor graph —
     a (p,) chain in 1D, a (pr, pc) grid in 2D.  The engine builds the
-    mesh itself when the visible device count equals p (e.g. under
+    mesh itself from the first p visible devices when at least p are
+    visible (e.g. under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8``), or accepts
-    an explicit ``mesh=``; a device-count mismatch is rejected up front.
+    an explicit ``mesh=``; too few devices, or a mesh of another size,
+    is rejected up front.  On the mesh each device packs its own
+    subdomain's block (``ddkf.pack_operator(mesh=)``).
     ``overlap`` (>= 0, validated here for every domain) is the Schwarz
     halo width in mesh columns/rows absorbed from each grid-graph
     neighbour, with ``mu`` the overlap regularization of eq. 25-26.
@@ -194,6 +197,11 @@ def _domain_from_config(cfg: EngineConfig) -> domain_mod.Domain:
                      f"'kdtree' (got {cfg.domain_kind!r})")
 
 
+def _placed_bytes(x: jax.Array) -> int:
+    """Bytes of ``x`` held on its devices, summed over every copy."""
+    return sum(int(s.data.nbytes) for s in x.addressable_shards)
+
+
 # Checkpoint-tree key prefix for the domain's boundary-state arrays.
 _DOMAIN_PREFIX = "domain/"
 
@@ -242,6 +250,9 @@ class _Prepared:
                                         # pricing: outer axes full-vector)
     window: int = -1                    # time-window id (parallel-in-time
                                         # runs); -1 on sequential cycles
+    placed_bytes: int = 0               # bytes of H1 (and, on the first
+                                        # prepare, H0) copied host ->
+                                        # devices, over every device
 
 
 @dataclasses.dataclass
@@ -374,16 +385,18 @@ class AssimilationEngine:
 
         The solver needs one device per subdomain, laid out as the
         domain's processor graph (``domain.mesh_axes()``: a (p,) chain in
-        1D, a (pr, pc) grid in 2D).  A mismatched device count is
-        rejected here, up front, with the fix spelled out — downstream it
-        would only surface as an opaque shard_map shape error.
+        1D, a (pr, pc) grid in 2D), built from the first p visible
+        devices.  Fewer devices than p, or a given mesh of another size,
+        is rejected here, up front, with the fix spelled out — downstream
+        it would only surface as an opaque shard_map shape error.
         """
         if self.cfg.solver != "shardmap":
             return mesh, mesh_axis
         names, shape = self.domain.mesh_axes()
         if mesh is None:
-            n_dev = len(jax.devices())
-            if n_dev != self.p:
+            devices = jax.devices()
+            n_dev = len(devices)
+            if n_dev < self.p:
                 raise ValueError(
                     f"solver='shardmap' requires a mesh with one device "
                     f"per subdomain: p={self.p} but {n_dev} JAX device(s) "
@@ -393,7 +406,8 @@ class AssimilationEngine:
                     f"config's p/pr*pc to the hardware")
             mesh = jax.make_mesh(
                 tuple(shape), tuple(names),
-                axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+                axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                devices=devices[:self.p])
             return mesh, (names if len(names) > 1 else names[0])
         n_mesh = int(np.prod(list(mesh.shape.values())))
         if n_mesh != self.p:
@@ -473,11 +487,27 @@ class AssimilationEngine:
             return None
         return self.cfg.halo_weight * self._current_dec().halo_sizes
 
+    @property
+    def _pack_mesh(self):
+        """The mesh the packing is laid out on: the sharded solver's,
+        None for the single-device solver."""
+        return self.mesh if self.cfg.solver == "shardmap" else None
+
+    def _to_devices(self, x: np.ndarray) -> jax.Array:
+        """A host array copied to the device(s) the packing runs on, in
+        the device's dtype: whole on every device of the mesh, or to
+        the default device."""
+        mesh = self._pack_mesh
+        if mesh is None:
+            return jnp.asarray(x)
+        return jax.device_put(x, jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()))
+
     def _H0_device(self) -> jax.Array:
-        """H0 on the device, in the device's dtype: copied once, on the
+        """H0 on the device(s), in the device's dtype: copied once, on the
         first prepare, since H0 never changes."""
         if self._H0_dev is None:
-            self._H0_dev = jnp.asarray(self._H0)
+            self._H0_dev = self._to_devices(self._H0)
         return self._H0_dev
 
     def prepare(self, cycle: int, obs: np.ndarray,
@@ -543,11 +573,17 @@ class AssimilationEngine:
                 H1 = cls_mod.observation_operator(
                     self.n, self.domain.obs_positions(obs),
                     block=self.domain.row_size)
-            with trace_mod.phase(phases, "pack.roundtrip") as ph:
-                # H1, the only part of A that changes from cycle to
-                # cycle, crosses to the device one way, in the device's
-                # dtype (float32 unless x64 is on).
-                H1_dev = ph.fence(jnp.asarray(H1))
+            # H1, the only part of A that changes from cycle to cycle,
+            # crosses to the device one way, in the device's dtype
+            # (float32 unless x64 is on): on a mesh to every device, so
+            # that each packs its own block.  H0 went with the first.
+            step = "pack.roundtrip" if self._pack_mesh is None \
+                else "pack.place"
+            with trace_mod.phase(phases, step) as ph:
+                placed = 0 if self._H0_dev is not None else _placed_bytes(
+                    self._H0_device())
+                H1_dev = ph.fence(self._to_devices(H1))
+                placed += _placed_bytes(H1_dev)
             with trace_mod.phase(phases, "pack.concat") as ph:
                 A = ph.fence(jnp.concatenate([self._H0_device(), H1_dev]))
                 r = np.ones((A.shape[0],))
@@ -557,7 +593,7 @@ class AssimilationEngine:
             # under double buffering) so pack_time is honest.
             packed_op = ddkf_mod.pack_operator(
                 A, r, dec, mu=cfg.mu, solver_kernel=cfg.solver_kernel,
-                phases=phases)
+                phases=phases, mesh=self._pack_mesh, axis=self.mesh_axis)
 
         with trace_mod.phase(phases, "data", cycle=cycle):
             # Truth-driven observation data: the truth random-walks each
@@ -609,7 +645,7 @@ class AssimilationEngine:
                          comm_edge_bytes_per_cycle=edge_bytes,
                          comm_mvec_bytes_per_cycle=float(mvec_bytes),
                          comm_mvec_axis_bytes_per_cycle=mvec_axis_bytes,
-                         window=window)
+                         window=window, placed_bytes=placed)
 
     # -- device-side solve (main thread) -----------------------------------
 
@@ -674,7 +710,9 @@ class AssimilationEngine:
                         "solve", t0, dt,
                         track=f"device {sh.index[0].start or 0}",
                         cycle=prep.cycle)
-                x = x_pd[0]
+                # Row 0 as device 0 holds it: indexing the sharded x_pd
+                # would send the index to every device.
+                x = shards[0].data[0]
             else:
                 out = ddkf_mod.solve_vmapped(
                     packed, iters=cfg.iters, damping=cfg.damping,
@@ -941,7 +979,8 @@ class AssimilationEngine:
                 prep.comm_mvec_axis_bytes_per_cycle),
             device_solve_times=[float(t) for t in device_times],
             straggler_flags=flags,
-            window=prep.window))
+            window=prep.window,
+            placed_bytes=prep.placed_bytes))
 
     # -- checkpoint / resume ------------------------------------------------
 

@@ -69,7 +69,9 @@ class CycleMetrics:
                                 # per-phase host durations (s): count,
                                 # dydd, halo, pack (and its steps
                                 # pack.h1/concat/roundtrip/fill/h2d/
-                                # factor), data, solve (and solve.input,
+                                # factor, pack.place in place of
+                                # roundtrip on the sharded solver's
+                                # mesh), data, solve (and solve.input,
                                 # solve.device) — the span timings,
                                 # journalled even when no tracer is
                                 # installed
@@ -112,6 +114,13 @@ class CycleMetrics:
                                 # partition is a pure function of the
                                 # cycle count), so it stays in the
                                 # bitwise deterministic_dict view
+    placed_bytes: int = 0       # bytes of the observation operator the
+                                # cycle copied host -> devices, summed
+                                # over every device it went to: H1 (to
+                                # each device of the sharded solver's
+                                # mesh, journal phase pack.place, or to
+                                # the one device, pack.roundtrip), and H0
+                                # on an engine's first cycle
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -245,10 +254,11 @@ class Journal:
     # Wall-clock-derived record fields: identical inputs produce
     # different values across runs, so the resume/chaos bitwise
     # comparisons strip them (everything else in a record is a pure
-    # function of stream + seed + config).
+    # function of stream + seed + config).  placed_bytes depends on what
+    # the engine had already placed: a resumed engine copies H0 again.
     NONDETERMINISTIC_FIELDS = ("pack_time", "solve_time", "cycle_time",
                                "phases", "compiles", "device_solve_times",
-                               "straggler_flags")
+                               "straggler_flags", "placed_bytes")
 
     def deterministic_dict(self) -> dict:
         """``to_dict`` minus wall-clock fields and resume bookkeeping —

@@ -41,7 +41,7 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import cls as cls_mod
 from repro.core import dd as dd_mod
@@ -231,10 +231,19 @@ def _resolve_solver_kernel(solver_kernel: str) -> str:
     return solver_kernel
 
 
-@partial(jax.jit, static_argnames=("gram_mode", "gram_block"))
+def _sub_spec(axis) -> P:
+    """The PartitionSpec that puts subdomain i on device i of the mesh
+    axis ``axis`` (one name, or a tuple of names for a grid mesh)."""
+    axes = (axis,) if isinstance(axis, str) else tuple(axis)
+    return P(axes if len(axes) > 1 else axes[0])
+
+
+@partial(jax.jit, static_argnames=("gram_mode", "gram_block", "mesh",
+                                   "axis"))
 def _factor_batched(A_loc: jax.Array, r: jax.Array, diag_add: jax.Array,
                     gram_mode: str = "auto",
-                    gram_block: int | None = None) -> jax.Array:
+                    gram_block: int | None = None,
+                    mesh=None, axis="sub") -> jax.Array:
     """Batched local normal matrices + Cholesky factors, on device.
 
     N_i = A_i^T diag(r) A_i comes from the ``kernels.ops.gram`` kernel
@@ -242,31 +251,60 @@ def _factor_batched(A_loc: jax.Array, r: jax.Array, diag_add: jax.Array,
     mu-regularization on overlap slots plus the identity on padded slots
     that keeps every factor nonsingular.  ``gram_block`` is the autotuned
     reduction tile, resolved by the caller outside jit
-    (``ops.gram_block_for``).
+    (``ops.gram_block_for``).  With a ``mesh``, ``A_loc`` and
+    ``diag_add`` are sharded over its ``axis`` and each device factors
+    its own block.
     """
-    p = A_loc.shape[0]
-    N = ops_mod.gram(A_loc, jnp.broadcast_to(r, (p, r.shape[0])),
-                     mode=gram_mode, block_m=gram_block)
-    N = N + jax.vmap(jnp.diag)(diag_add.astype(N.dtype))
-    return jax.vmap(jnp.linalg.cholesky)(N)
+    def build(A_loc, r, diag_add):
+        p = A_loc.shape[0]
+        N = ops_mod.gram(A_loc, jnp.broadcast_to(r, (p, r.shape[0])),
+                         mode=gram_mode, block_m=gram_block)
+        N = N + jax.vmap(jnp.diag)(diag_add.astype(N.dtype))
+        return jax.vmap(jnp.linalg.cholesky)(N)
+
+    if mesh is None:
+        return build(A_loc, r, diag_add)
+    sub = _sub_spec(axis)
+    return jax.shard_map(build, mesh=mesh, in_specs=(sub, P(), sub),
+                         out_specs=sub, check_vma=False)(A_loc, r, diag_add)
 
 
-@jax.jit
+@partial(jax.jit, static_argnames=("mesh", "axis"))
 def _gather_blocks(A: jax.Array, gather_cols: jax.Array,
-                   cols: jax.Array) -> jax.Array:
+                   cols: jax.Array, mesh=None, axis="sub") -> jax.Array:
     """The padded local blocks ``A_loc[i, :, j] = A[:, cols[i, j]]``,
     gathered on the device: exact +0.0 on padded slots (``cols == -1``),
     the columns copied, so the result equals a host fill bit for bit.
     Gathers rows of ``A.T``, whole rows along the lanes, and turns the
-    (p, w, m) result to (p, m, w)."""
-    rows = jnp.take(A.T, gather_cols, axis=0, mode="clip")
-    return jnp.where((cols >= 0)[:, None, :], jnp.swapaxes(rows, 1, 2), 0)
+    (p, w, m) result to (p, m, w).  With a ``mesh``, ``A`` is whole on
+    every device, the index maps are sharded over its ``axis``, and each
+    device gathers its own block."""
+    def gather(A, gather_cols, cols):
+        rows = jnp.take(A.T, gather_cols, axis=0, mode="clip")
+        return jnp.where((cols >= 0)[:, None, :], jnp.swapaxes(rows, 1, 2),
+                         0)
+
+    if mesh is None:
+        return gather(A, gather_cols, cols)
+    sub = _sub_spec(axis)
+    return jax.shard_map(gather, mesh=mesh, in_specs=(P(), sub, sub),
+                         out_specs=sub, check_vma=False)(A, gather_cols, cols)
+
+
+def _place(x, sharding, dtype=None):
+    """``x`` (as ``dtype``, when given) laid out by ``sharding``, straight
+    from the host when ``x`` is a numpy array."""
+    if dtype is not None:
+        x = (np.asarray(x, dtype) if isinstance(x, np.ndarray)
+             else jnp.asarray(x, dtype))
+    return jax.device_put(x, sharding)
 
 
 def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
                   mu: float = 1.0, gram_mode: str = "auto",
                   solver_kernel: str = "auto",
-                  phases: dict | None = None) -> PackedDD:
+                  phases: dict | None = None, mesh=None,
+                  axis="sub") -> PackedDD:
     """Pack the *operator* part of a decomposed CLS problem.
 
     ``A`` (m, n) may be a numpy or a device array; it is used on the
@@ -281,6 +319,14 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     engine runs it for cycle t+1 while the device is solving cycle t,
     then injects the cycle's rhs with :func:`with_rhs` (a cheap
     ``dataclasses.replace``).
+
+    With a ``mesh`` (one device per subdomain over its ``axis``, as
+    :func:`solve_shardmap` takes it) each device packs its own
+    subdomain: ``A`` is placed whole on every device (a no-op for an
+    ``A`` already laid out so), the (p, ...) fields come out sharded
+    over ``axis`` and ``r``, ``b`` and ``mult`` whole on every device,
+    the layout the sharded solve's ``in_specs`` name, so dispatching it
+    moves no operator bytes between devices.
 
     ``gram_mode`` selects the kernel path ("auto": Pallas on TPU, jnp
     reference elsewhere — see :mod:`repro.kernels.ops`).
@@ -301,7 +347,18 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     The returned ``PackedDD`` carries a zero rhs; pass it through
     :func:`with_rhs` before solving.
     """
-    A = jnp.asarray(A)
+    if mesh is None:
+        A = jnp.asarray(A)
+        sub = None
+
+        def put(x, spec, dtype=None):
+            return jnp.asarray(x, dtype)
+    else:
+        A = _place(A, NamedSharding(mesh, P()))
+        sub = _sub_spec(axis)
+
+        def put(x, spec, dtype=None):
+            return _place(x, NamedSharding(mesh, spec), dtype)
     m, n = A.shape
     p = dec.p
     dtype = np.dtype(A.dtype)
@@ -332,20 +389,21 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
         # here instead of a where(cols >= 0, ...) membership mask per call.
         mult_loc = np.where(cols >= 0, mult_at, 1.0)
         scatter_cols = np.where(cols >= 0, cols, n)
-        gather_cols = jnp.asarray(np.where(cols >= 0, cols, 0))
-        cols = jnp.asarray(cols)
-        A_loc = ph.fence(_gather_blocks(A, gather_cols, cols))
+        gather_cols = put(np.where(cols >= 0, cols, 0), sub)
+        cols = put(cols, sub)
+        A_loc = ph.fence(_gather_blocks(A, gather_cols, cols, mesh=mesh,
+                                        axis=axis))
 
     with trace_mod.phase(phases, "pack.h2d") as ph:
-        r = jnp.asarray(r, A_loc.dtype)
-        diag_add = jnp.asarray(diag_add)
-        dev = dict(cols=cols, mask=jnp.asarray(mask),
-                   muov=jnp.asarray(muov), wdiv=jnp.asarray(wdiv),
-                   mult=jnp.asarray(np.maximum(counts, 1)).astype(A.dtype),
-                   mult_loc=jnp.asarray(mult_loc, A_loc.dtype),
-                   scatter_cols=jnp.asarray(scatter_cols),
+        r = put(r, P(), A_loc.dtype)
+        diag_add = put(diag_add, sub)
+        dev = dict(cols=cols, mask=put(mask, sub), muov=put(muov, sub),
+                   wdiv=put(wdiv, sub),
+                   mult=put(np.maximum(counts, 1), P(), A.dtype),
+                   mult_loc=put(mult_loc, sub, A_loc.dtype),
+                   scatter_cols=put(scatter_cols, sub),
                    gather_cols=gather_cols,
-                   b=jnp.zeros((m,), dtype=A_loc.dtype))
+                   b=put(np.zeros((m,)), P(), A_loc.dtype))
         ph.fence((r, diag_add, dev))
 
     with trace_mod.phase(phases, "pack.factor") as ph:
@@ -357,7 +415,8 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
                                             mode=gram_mode)
         L_loc = ph.fence(_factor_batched(A_loc, r, diag_add,
                                          gram_mode=gram_mode,
-                                         gram_block=gram_block))
+                                         gram_block=gram_block, mesh=mesh,
+                                         axis=axis))
         solve_kernel = _resolve_solver_kernel(solver_kernel)
         solve_block = (ops_mod.schwarz_block_for(
             (p, m, w), A_loc.dtype, mode=_KERNEL_OPS_MODE[solve_kernel])
@@ -368,8 +427,14 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
 
 
 def with_rhs(packed: PackedDD, b: jax.Array) -> PackedDD:
-    """Inject the data vector b = [y0; y1] into an operator-only packing."""
-    return dataclasses.replace(packed, b=jnp.asarray(b, packed.A_loc.dtype))
+    """Inject the data vector b = [y0; y1] into an operator-only packing:
+    laid out like the packing's ``r``, so a packing sharded over a mesh
+    takes its rhs whole on every device, from the host."""
+    if isinstance(packed.r.sharding, NamedSharding):
+        b = _place(b, packed.r.sharding, packed.A_loc.dtype)
+    else:
+        b = jnp.asarray(b, packed.A_loc.dtype)
+    return dataclasses.replace(packed, b=b)
 
 
 def pad_packed_width(packed: PackedDD, w_new: int) -> PackedDD:
@@ -734,15 +799,19 @@ def solve_shardmap(packed: PackedDD, mesh, axis="sub",
     # pack/unpack payload maps travel as operands — int32 end to end
     # (the schedule indexes w + 1 <= 2^31 slots; int64 operands would
     # silently downcast under default x32 and double the index payload).
+    # They and the damping go from the host straight to the devices that
+    # use them, so a packing laid out by ``pack_operator(mesh=)`` is
+    # dispatched with no copy between devices.
     empty = np.zeros((packed.p, 0, 0), np.int32)
-    pack_idx = jnp.asarray(halo.pack_idx if comm == "neighbour" else empty,
-                           jnp.int32)
-    unpack_idx = jnp.asarray(halo.unpack_idx if comm == "neighbour"
-                             else empty, jnp.int32)
+    sub = NamedSharding(mesh, _sub_spec(axes))
+    pack_idx = _place(halo.pack_idx if comm == "neighbour" else empty, sub,
+                      np.int32)
+    unpack_idx = _place(halo.unpack_idx if comm == "neighbour" else empty,
+                        sub, np.int32)
     fn = _shardmap_fn(mesh, axes, iters, comm, mvec, perms,
                       residual_history, return_per_device)
     out, hist = fn(packed, pack_idx, unpack_idx,
-                   jnp.asarray(damping, packed.A_loc.dtype))
+                   np.asarray(damping, packed.A_loc.dtype))
     if residual_history:
         return out, hist
     return out
@@ -778,7 +847,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
                                      tiled=True)
         return jax.lax.all_gather(chunk, axes[-1], tiled=True)
 
-    def run(packed, pack_idx, unpack_idx, damping):
+    def _solve_shard_map(packed, pack_idx, unpack_idx, damping):
         n, m, w, kern = packed.n, packed.m, packed.w, packed.solve_kernel
         n_pad = -(-(n + 1) // ks) * ks
         m_pad = -(-m // ks) * ks
@@ -876,7 +945,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
             return ((axis_allreduce(scatter_part(x_i))[:n] / mult)[None],
                     hist[None])
 
-        specs = P(axes if len(axes) > 1 else axes[0])
+        specs = _sub_spec(axes)
         out, hist = jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(specs,) * 10 + (P(),) * 4,
@@ -887,7 +956,8 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
                 packed.mult, damping)
         return (out if return_per_device else out[0]), hist[0]
 
-    fn = jax.jit(run)
+    # Named so that its device module reads jit__solve_shard_map.
+    fn = jax.jit(_solve_shard_map)
     _SHARDMAP_CACHE[key] = fn
     return fn
 

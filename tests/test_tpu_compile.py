@@ -175,9 +175,43 @@ def test_solve_shardmap_compiles_on_4_chips(topo, monkeypatch, comm):
                         _sds(sub, *idx, dtype=i32),
                         _sds(rep, dtype=F32)).compile()
     text = compiled.as_text()
+    assert text.startswith("HloModule jit__solve_shard_map,")
     assert _kernels(compiled) == 2          # schwarz_fwd + schwarz_bwd
     # The compiler fuses the reduce-scatter + all-gather pairs back into
     # all-reduces on this mesh.
     assert "all-reduce" in text
     if comm == "neighbour":
         assert "collective-permute" in text
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+def test_sharded_pack_compiles_on_4_chips(topo, monkeypatch):
+    """Each chip gathers and factors its own block at Example 4's p = 4,
+    overlap-1 width (w = 1257, the width DyDD gives the Beta(2, 5)
+    network): from an A whole on every chip, the two programs hold no
+    collective, so no block crosses between chips, and each chip's
+    gather writes its one (1, m, w) block with no temporaries."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("sub",))
+    sub, rep = NamedSharding(mesh, P("sub")), NamedSharding(mesh, P())
+    p, w, i32 = 4, 1257, jnp.int32
+    gather = ddkf._gather_blocks.lower(
+        _sds(rep, M, N), _sds(sub, p, w, dtype=i32),
+        _sds(sub, p, w, dtype=i32), mesh=mesh, axis="sub").compile()
+    factor = ddkf._factor_batched.lower(
+        _sds(sub, p, M, w), _sds(rep, M), _sds(sub, p, w),
+        gram_block=512, mesh=mesh, axis="sub").compile()
+    for compiled, module in ((gather, "jit__gather_blocks"),
+                             (factor, "jit__factor_batched")):
+        text = compiled.as_text()
+        assert text.startswith(f"HloModule {module},")
+        assert not any(c in text for c in COLLECTIVES), module
+    assert " gather(" in gather.as_text()
+    assert _kernels(factor) == 1            # the gram, per chip
+    mem = gather.memory_analysis()
+    # one block, not four, up to its tile padding
+    assert M * w * 4 <= mem.output_size_in_bytes < 1.1 * M * w * 4
+    assert mem.temp_size_in_bytes == 0

@@ -139,7 +139,7 @@ def solve_bound(meta: dict, config: dict, kernel: str,
     Rebuilds the arm's *initial* decomposition (DyDD may move boundaries
     later; w only shrinks under balancing, so this is the conservative
     shape).  Per device and iteration: ~6mw + 2w^2 flops (two stacked
-    matmats + the transpose product + the triangular solves), operator
+    matmats + the transpose product + the local inverse matvec), operator
     bytes = passes * m * w * itemsize with passes = 2 fused / 3 jnp, and
     the collective term is comm_model's per-device pricing of the
     configured exchange under the domain's torus mesh shape.

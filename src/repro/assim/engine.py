@@ -11,8 +11,8 @@ The engine consumes an observation stream cycle by cycle and, per cycle:
      ``dydd_2d``'s per-axis passes on a :class:`ShelfTiling2D`);
   2. decomposes the state index set on the (possibly moved) boundaries and
      packs the local operator blocks — the device-side gather of the
-     padded blocks from ``A = [H0; H1]`` plus the batched normal-matrix/
-     Cholesky build (``ddkf.pack_operator``, ``kernels.ops.gram``);
+     padded blocks from ``A = [H0; H1]`` plus the batched normal-matrix
+     inverse build (``ddkf.pack_operator``, ``kernels.ops.gram``);
   3. injects the cycle's right-hand side (background carried forward from
      the previous analysis + fresh observation data) and runs the sharded
      DD-KF solve (``ddkf.solve_vmapped`` / ``solve_shardmap``);
@@ -86,7 +86,7 @@ class EngineConfig:
     load ratio against the incoming boundaries has exceeded
     ``imbalance_threshold`` for ``hysteresis`` consecutive cycles.  The
     hysteresis keeps a near-balanced network from thrashing boundaries
-    (and recompiling nothing, but re-factoring p local Cholesky blocks)
+    (and recompiling nothing, but re-inverting p local normal matrices)
     every cycle on noise.
     """
 

@@ -50,7 +50,7 @@ from repro.obs import trace as trace_mod
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=("A_loc", "L_loc", "cols", "mask", "muov", "wdiv",
+         data_fields=("A_loc", "Ninv_loc", "cols", "mask", "muov", "wdiv",
                       "mult", "mult_loc", "scatter_cols", "gather_cols",
                       "r", "b"),
          meta_fields=("n", "p", "w", "solve_kernel", "solve_block"))
@@ -59,7 +59,8 @@ class PackedDD:
     """Host-side packing of a Decomposition into padded device arrays."""
 
     A_loc: jax.Array      # (p, m, w) local column blocks, zero-padded
-    L_loc: jax.Array      # (p, w, w) Cholesky of local normal matrices
+    Ninv_loc: jax.Array   # (p, w, w) inverses of the local normal matrices
+                          # (identity on padded slots)
     cols: jax.Array       # (p, w) global column index per local slot (or -1)
     mask: jax.Array       # (p, w) 1.0 for real columns, 0.0 for padding
     muov: jax.Array       # (p, w) mu on overlap slots (regularization)
@@ -244,23 +245,30 @@ def _factor_batched(A_loc: jax.Array, r: jax.Array, diag_add: jax.Array,
                     gram_mode: str = "auto",
                     gram_block: int | None = None,
                     mesh=None, axis="sub") -> jax.Array:
-    """Batched local normal matrices + Cholesky factors, on device.
+    """Batched inverses of the local normal matrices, on device.
 
     N_i = A_i^T diag(r) A_i comes from the ``kernels.ops.gram`` kernel
     (Pallas on TPU, jnp reference elsewhere); ``diag_add`` carries the
     mu-regularization on overlap slots plus the identity on padded slots
-    that keeps every factor nonsingular.  ``gram_block`` is the autotuned
-    reduction tile, resolved by the caller outside jit
-    (``ops.gram_block_for``).  With a ``mesh``, ``A_loc`` and
-    ``diag_add`` are sharded over its ``axis`` and each device factors
-    its own block.
+    that keeps every block nonsingular (so the inverse is the identity
+    there too).  N_i^-1 = L^-T L^-1 from its Cholesky factor L, in full
+    float32 products: every Schwarz sweep then applies it as one matvec
+    (:func:`_local_solve`) instead of two triangular solves.
+    ``gram_block`` is the autotuned reduction tile, resolved by the
+    caller outside jit (``ops.gram_block_for``).  With a ``mesh``,
+    ``A_loc`` and ``diag_add`` are sharded over its ``axis`` and each
+    device inverts its own block.
     """
     def build(A_loc, r, diag_add):
         p = A_loc.shape[0]
         N = ops_mod.gram(A_loc, jnp.broadcast_to(r, (p, r.shape[0])),
                          mode=gram_mode, block_m=gram_block)
         N = N + jax.vmap(jnp.diag)(diag_add.astype(N.dtype))
-        return jax.vmap(jnp.linalg.cholesky)(N)
+        L = jax.vmap(jnp.linalg.cholesky)(N)
+        eye = jnp.broadcast_to(jnp.eye(N.shape[-1], dtype=N.dtype), N.shape)
+        L_inv = jax.scipy.linalg.solve_triangular(L, eye, lower=True)
+        return jnp.einsum("pki,pkj->pij", L_inv, L_inv,
+                          precision=jax.lax.Precision.HIGHEST)
 
     if mesh is None:
         return build(A_loc, r, diag_add)
@@ -312,9 +320,9 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     small (p, w) index maps of the decomposition; the p column blocks
     are gathered from ``A`` into the padded (p, m, w) layout on the
     device (:func:`_gather_blocks`), and the p local normal matrices
-    N_i = A_i^T diag(r) A_i and their Cholesky factors are then built
-    on the device in one batched shot (:func:`_factor_batched`:
-    ``kernels.ops.gram`` + ``vmap(cholesky)``).  The packing depends
+    N_i = A_i^T diag(r) A_i and their inverses are then built on the
+    device in one batched shot (:func:`_factor_batched`:
+    ``kernels.ops.gram``, Cholesky, inverse).  The packing depends
     only on (A, r, dec), not on the data vector b, so the streaming
     engine runs it for cycle t+1 while the device is solving cycle t,
     then injects the cycle's rhs with :func:`with_rhs` (a cheap
@@ -341,7 +349,7 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     ``pack.fill`` (the index maps and the device gather of the padded
     blocks), ``pack.h2d`` (the copy of the other maps, ``r`` and the
     diagonal to the device) and ``pack.factor`` (the block lookups or
-    autotune and the factor build).  Without it nothing is written and
+    autotune and the inverse build).  Without it nothing is written and
     nothing blocks.
 
     The returned ``PackedDD`` carries a zero rhs; pass it through
@@ -413,15 +421,15 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
         # in the packing's meta fields.
         gram_block = ops_mod.gram_block_for((p, m, w), A_loc.dtype,
                                             mode=gram_mode)
-        L_loc = ph.fence(_factor_batched(A_loc, r, diag_add,
-                                         gram_mode=gram_mode,
-                                         gram_block=gram_block, mesh=mesh,
-                                         axis=axis))
+        Ninv_loc = ph.fence(_factor_batched(A_loc, r, diag_add,
+                                            gram_mode=gram_mode,
+                                            gram_block=gram_block,
+                                            mesh=mesh, axis=axis))
         solve_kernel = _resolve_solver_kernel(solver_kernel)
         solve_block = (ops_mod.schwarz_block_for(
             (p, m, w), A_loc.dtype, mode=_KERNEL_OPS_MODE[solve_kernel])
             if solve_kernel != "jnp" else None)
-    return PackedDD(A_loc=A_loc, L_loc=L_loc, r=r, n=n, p=p, w=w,
+    return PackedDD(A_loc=A_loc, Ninv_loc=Ninv_loc, r=r, n=n, p=p, w=w,
                     solve_kernel=solve_kernel, solve_block=solve_block,
                     **dev)
 
@@ -445,7 +453,7 @@ def pad_packed_width(packed: PackedDD, w_new: int) -> PackedDD:
     (:func:`stack_packed` requires equal ``w``).  Padding widens every
     per-slot field with the same conventions ``pack_operator`` uses for
     its own padding — zero columns in ``A_loc``, identity diagonal in
-    ``L_loc``, ``cols=-1``/``mask=0``, multiplicity 1, scatter to the
+    ``Ninv_loc``, ``cols=-1``/``mask=0``, multiplicity 1, scatter to the
     dump slot ``n`` — so the padded slots solve to exactly zero and the
     assembled estimate is unchanged up to reduction order.  This is a
     *tolerance-path* helper (the window-stacked Parareal fine solves):
@@ -459,15 +467,15 @@ def pad_packed_width(packed: PackedDD, w_new: int) -> PackedDD:
         return packed
     pad = w_new - packed.w
     p, w = packed.p, packed.w
-    L = jnp.zeros((p, w_new, w_new), packed.L_loc.dtype)
-    L = L.at[:, :w, :w].set(packed.L_loc)
+    Ninv = jnp.zeros((p, w_new, w_new), packed.Ninv_loc.dtype)
+    Ninv = Ninv.at[:, :w, :w].set(packed.Ninv_loc)
     diag = jnp.arange(w, w_new)
-    L = L.at[:, diag, diag].set(1.0)
+    Ninv = Ninv.at[:, diag, diag].set(1.0)
     pad2 = ((0, 0), (0, pad))
     return dataclasses.replace(
         packed,
         A_loc=jnp.pad(packed.A_loc, ((0, 0), (0, 0), (0, pad))),
-        L_loc=L,
+        Ninv_loc=Ninv,
         cols=jnp.pad(packed.cols, pad2, constant_values=-1),
         mask=jnp.pad(packed.mask, pad2),
         muov=jnp.pad(packed.muov, pad2),
@@ -479,18 +487,21 @@ def pad_packed_width(packed: PackedDD, w_new: int) -> PackedDD:
         w=w_new)
 
 
-def _chol_solve(L, rhs):
-    z = jax.scipy.linalg.solve_triangular(L, rhs, lower=True)
-    return jax.scipy.linalg.solve_triangular(L.T, z, lower=False)
+def _local_solve(Ninv, rhs):
+    """N_i^-1 rhs_i for every leading batch index, against the inverse
+    built once per packing: a float32 multiply and lane reduce, which
+    XLA fuses into one pass over ``Ninv`` (a dot at default precision
+    would round the operands to bfloat16)."""
+    return jnp.sum(Ninv * rhs[..., None, :], axis=-1)
 
 
-def _local_update(A_i, L_i, mask_i, muov_i, x_i, Ax, r, b):
+def _local_update(A_i, Ninv_i, mask_i, muov_i, x_i, Ax, r, b):
     """One local regularized VAR-KF solve given the global product Ax
     (eq. 25/27): the mu-term anchors the overlap slots to the current
     consistent global iterate x_i (= x_glob gathered)."""
     resid = b - Ax + A_i @ x_i
     rhs = (A_i.T @ (r * resid) + muov_i * x_i) * mask_i
-    return _chol_solve(L_i, rhs) * mask_i
+    return _local_solve(Ninv_i, rhs) * mask_i
 
 
 # ---------------------------------------------------------------------------
@@ -533,9 +544,10 @@ def solve_vmapped(packed: PackedDD, iters: int = 60,
                                   x_loc * packed.wdiv)
             Ax = jnp.sum(Ax_parts, axis=0)
             new = jax.vmap(
-                lambda A_i, L_i, m_i, mu_i, x_i: _local_update(
-                    A_i, L_i, m_i, mu_i, x_i, Ax, packed.r, packed.b)
-            )(packed.A_loc, packed.L_loc, packed.mask, packed.muov, x_loc)
+                lambda A_i, N_i, m_i, mu_i, x_i: _local_update(
+                    A_i, N_i, m_i, mu_i, x_i, Ax, packed.r, packed.b)
+            )(packed.A_loc, packed.Ninv_loc, packed.mask, packed.muov,
+              x_loc)
         else:
             mode = _KERNEL_OPS_MODE[kern]
             y, u = ops_mod.schwarz_fwd(packed.A_loc, x_loc, packed.wdiv,
@@ -546,7 +558,7 @@ def solve_vmapped(packed: PackedDD, iters: int = 60,
                                       Ax, u, x_loc, packed.muov,
                                       packed.mask, mode=mode,
                                       block_m=packed.solve_block)
-            new = jax.vmap(_chol_solve)(packed.L_loc, rhs) * packed.mask
+            new = _local_solve(packed.Ninv_loc, rhs) * packed.mask
         x_loc2 = (1.0 - damping) * x_loc + damping * new
         # Overlap consistency: average duplicated columns globally, then
         # gather back (eq. 28).
@@ -683,7 +695,7 @@ def solve_fleet(stacked: PackedDD, iters: int = 60, damping: float = 1.0,
     each problem executes the *identical op graph* as a standalone
     :func:`solve_vmapped` call, so the fleet results are **bitwise
     identical** to per-problem solves (an extra ``vmap`` axis would
-    reassociate the matvec/triangular-solve reductions; ``lax.map`` does
+    reassociate the matvec and local-solve reductions; ``lax.map`` does
     not).  With ``mesh=`` the problem axis is additionally sharded over
     the ``axis`` mesh axis via ``shard_map`` — one slice of the cohort
     per device, still ``lax.map`` inside, still bitwise — which is where
@@ -860,11 +872,11 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
                     [part, jnp.zeros((m_pad - m,), part.dtype)])
             return axis_allreduce(part)[:m]
 
-        def per_device(A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i,
+        def per_device(A_i, N_i, mask_i, muov_i, wdiv_i, scat_i, gath_i,
                        mloc_i, pack_i, unpack_i, r, b, mult, damping):
             # Leading axis of size 1 (= this device's subdomain).
-            (A_i, L_i, mask_i, muov_i, wdiv_i, scat_i, gath_i, mloc_i,
-             pack_i, unpack_i) = (A_i[0], L_i[0], mask_i[0], muov_i[0],
+            (A_i, N_i, mask_i, muov_i, wdiv_i, scat_i, gath_i, mloc_i,
+             pack_i, unpack_i) = (A_i[0], N_i[0], mask_i[0], muov_i[0],
                                   wdiv_i[0], scat_i[0], gath_i[0],
                                   mloc_i[0], pack_i[0], unpack_i[0])
 
@@ -908,7 +920,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
             def step(x_i):
                 if kern == "jnp":
                     Ax = mvec_allreduce(A_i @ (x_i * wdiv_i))
-                    new = _local_update(A_i, L_i, mask_i, muov_i, x_i, Ax,
+                    new = _local_update(A_i, N_i, mask_i, muov_i, x_i, Ax,
                                         r, b)
                 else:
                     mode = _KERNEL_OPS_MODE[kern]
@@ -920,7 +932,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
                                               x_i[None], muov_i[None],
                                               mask_i[None], mode=mode,
                                               block_m=packed.solve_block)[0]
-                    new = _chol_solve(L_i, rhs) * mask_i
+                    new = _local_solve(N_i, rhs) * mask_i
                 return exchange((1.0 - damping) * x_i + damping * new)
 
             x_i = jnp.zeros((w,), dtype=A_i.dtype)
@@ -950,7 +962,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
             per_device, mesh=mesh,
             in_specs=(specs,) * 10 + (P(),) * 4,
             out_specs=(specs, specs), check_vma=False)(
-                packed.A_loc, packed.L_loc, packed.mask, packed.muov,
+                packed.A_loc, packed.Ninv_loc, packed.mask, packed.muov,
                 packed.wdiv, packed.scatter_cols, packed.gather_cols,
                 packed.mult_loc, pack_idx, unpack_idx, packed.r, packed.b,
                 packed.mult, damping)
@@ -984,7 +996,7 @@ def _window_sharded_fn(mesh, time_axis: str, sub_axis: str, iters: int,
     # must split evenly over the sub axis for the reduce-scatter pair.
     n_pad = -(-(n + 1) // ks) * ks
 
-    def per_device(A, L, mask, muov, wdiv, scat, gath, mult, r, b, x0,
+    def per_device(A, Ninv, mask, muov, wdiv, scat, gath, mult, r, b, x0,
                    damping):
         # A: (Kl, pl, m, w) — this device's window slice x subdomain
         # slice; mult: (Kl, n); r, b, x0: (Kl, ·).  Windows are
@@ -1015,7 +1027,7 @@ def _window_sharded_fn(mesh, time_axis: str, sub_axis: str, iters: int,
                      + jnp.einsum("kpmw,kpw->kpm", A, x))
             rhs = (jnp.einsum("kpmw,kpm->kpw", A, r[:, None, :] * resid)
                    + muov * x) * mask
-            new = jax.vmap(jax.vmap(_chol_solve))(L, rhs) * mask
+            new = _local_solve(Ninv, rhs) * mask
             x2 = (1.0 - damping) * x + damping * new
             x_glob = assemble_glob(x2)
             return jax.vmap(lambda xg, g: xg[g])(x_glob, gath) * mask
@@ -1083,7 +1095,7 @@ def solve_window_stack(stacked: PackedDD, mesh, time_axis: str = "time",
     dt = stacked.A_loc.dtype
     x0 = (jnp.zeros((K, stacked.n), dt) if x0 is None
           else jnp.asarray(x0, dt))
-    out = fn(stacked.A_loc, stacked.L_loc, stacked.mask, stacked.muov,
+    out = fn(stacked.A_loc, stacked.Ninv_loc, stacked.mask, stacked.muov,
              stacked.wdiv, stacked.scatter_cols, stacked.gather_cols,
              stacked.mult, stacked.r, stacked.b, x0,
              jnp.asarray(damping, dt))

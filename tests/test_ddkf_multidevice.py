@@ -1,8 +1,8 @@
 """shard_map DD-KF under real (forced) multi-device XLA — the production
 communication path, exercised in a subprocess so the main test session
 keeps its single-device view — plus parity of the device-side batched
-operator packing (kernels.ops.gram + vmap(cholesky)) against the old
-per-subdomain numpy Cholesky loop."""
+operator packing (kernels.ops.gram, Cholesky, inverse) against a
+per-subdomain numpy loop."""
 import os
 import subprocess
 import sys
@@ -294,12 +294,13 @@ def test_timepar_time_sub_mesh_8_devices():
 
 
 # ---------------------------------------------------------------------------
-# Device-side operator packing parity vs the old numpy Cholesky loop.
+# Device-side operator packing parity vs a per-subdomain numpy loop.
 # ---------------------------------------------------------------------------
 
-def _pack_factors_numpy(A, r, dec, mu):
-    """The pre-refactor reference: per-subdomain numpy normal matrices and
-    Cholesky factors (what ddkf.pack_operator used to build on the host)."""
+def _pack_inverses_numpy(A, r, dec, mu):
+    """The host reference: per-subdomain numpy normal matrices, with the
+    identity on padded slots, and their inverses (what
+    ddkf.pack_operator builds on the device)."""
     A = np.asarray(A)
     r = np.asarray(r)
     m, n = A.shape
@@ -307,7 +308,7 @@ def _pack_factors_numpy(A, r, dec, mu):
     counts = np.zeros(n, dtype=np.int64)
     for c in dec.col_sets:
         counts[np.asarray(c)] += 1
-    L_ref = np.zeros((dec.p, w, w), dtype=A.dtype)
+    Ninv_ref = np.zeros((dec.p, w, w), dtype=A.dtype)
     for i, c in enumerate(dec.col_sets):
         c = np.asarray(c)
         k = c.shape[0]
@@ -319,8 +320,8 @@ def _pack_factors_numpy(A, r, dec, mu):
             N[:k, :k] += mu * np.diag(ov)
         pad = np.arange(k, w)
         N[pad, pad] = 1.0
-        L_ref[i] = np.linalg.cholesky(N)
-    return L_ref
+        Ninv_ref[i] = np.linalg.inv(N)
+    return Ninv_ref
 
 
 def _fill_blocks_numpy(A, dec):
@@ -407,8 +408,8 @@ def test_pack_operator_gram_matches_numpy_loop(overlap, mu):
     A, b, r = prob.stacked()
 
     packed = ddkf.pack_operator(A, r, dec, mu=mu)
-    L_ref = _pack_factors_numpy(A, r, dec, mu)
-    np.testing.assert_allclose(np.asarray(packed.L_loc), L_ref,
+    Ninv_ref = _pack_inverses_numpy(A, r, dec, mu)
+    np.testing.assert_allclose(np.asarray(packed.Ninv_loc), Ninv_ref,
                                rtol=1e-10, atol=1e-10)
     # and the packed solve still matches the direct CLS estimate
     x = ddkf.solve_vmapped(ddkf.with_rhs(packed, b), iters=150)
@@ -418,7 +419,7 @@ def test_pack_operator_gram_matches_numpy_loop(overlap, mu):
 
 def test_pack_operator_gram_interpret_mode_close():
     """Forcing the Pallas gram kernel (interpret mode, f32 accumulation)
-    keeps the factors within kernel tolerance of the f64 reference."""
+    keeps the inverses within kernel tolerance of the f64 reference."""
     rng = np.random.default_rng(4)
     obs = np.sort(rng.uniform(0, 1, 200))
     prob = cls.local_problem(jax.random.PRNGKey(1), 64, obs)
@@ -426,5 +427,104 @@ def test_pack_operator_gram_interpret_mode_close():
     A, _, r = prob.stacked()
     ref = ddkf.pack_operator(A, r, dec, gram_mode="ref")
     ker = ddkf.pack_operator(A, r, dec, gram_mode="interpret")
-    np.testing.assert_allclose(np.asarray(ker.L_loc),
-                               np.asarray(ref.L_loc), rtol=2e-3, atol=2e-3)
+    Ninv_ref = _pack_inverses_numpy(A, r, dec, 1.0)
+    np.testing.assert_allclose(np.asarray(ref.Ninv_loc), Ninv_ref,
+                               rtol=1e-10, atol=1e-10)
+    np.testing.assert_allclose(np.asarray(ker.Ninv_loc), Ninv_ref,
+                               rtol=2e-3, atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# The local solve applies the stored inverse: no triangular solve per sweep.
+# ---------------------------------------------------------------------------
+
+SCRIPT_LOCAL_SOLVE = r"""
+import re
+import jax
+jax.config.update("jax_enable_x64", True)
+import numpy as np, jax.numpy as jnp
+from repro.core import cls, dd, ddkf, dydd
+
+case = {case!r}
+rng = np.random.default_rng(0)
+obs = rng.beta(2, 5, size=400)
+prob = cls.local_problem(jax.random.PRNGKey(0), 128, obs)
+x_direct = np.asarray(cls.solve(prob))
+res = dydd.dydd_1d(obs, 8)
+dec = dd.decompose_1d(prob.n, res.boundaries, overlap=2)
+kernel = "fused_ref" if case == "vmapped_fused_ref" else "jnp"
+packed = ddkf.pack(prob, dec, solver_kernel=kernel)
+if case.startswith("vmapped"):
+    solve = lambda pk: ddkf.solve_vmapped(pk, iters=120)
+    xs = [solve(packed)]
+elif case == "shardmap":
+    mesh = jax.make_mesh((8,), ("sub",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    solve = lambda pk: ddkf.solve_shardmap(pk, mesh, iters=120,
+                                           comm="neighbour",
+                                           halo=dec.halo_exchange)
+    xs = [solve(packed)]
+else:
+    # two windows (the same problem) over ("time": 2, "sub": 4): two
+    # subdomains per device
+    mesh = jax.make_mesh((2, 4), ("time", "sub"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    packed = ddkf.stack_packed([packed, packed])
+    solve = lambda pk: ddkf.solve_window_stack(pk, mesh, iters=120)
+    xs = list(solve(packed))
+# A triangular solve lowers to the HLO op triangular-solve, or on the CPU
+# to a LAPACK trsm custom call named after the primitive.
+hlo = jax.jit(solve).lower(packed).as_text(dialect="hlo")
+assert not re.search(r"triangular|trsm", hlo, re.I), case
+assert "dot(" in hlo, case
+for x in xs:
+    err = float(np.linalg.norm(np.asarray(x) - x_direct))
+    assert err < 1e-9, (case, err)
+print("OK", case, err)
+"""
+
+
+@pytest.mark.parametrize("case", ["vmapped_jnp", "vmapped_fused_ref",
+                                  "shardmap", "window_stack"])
+def test_solves_apply_the_inverse_with_no_triangular_solve(case):
+    """Every layout of the Schwarz step applies the packing's stored
+    inverse as a matvec: the lowered solve holds no triangular solve,
+    and the estimate matches the direct CLS solve as the shard_map
+    equivalence test requires."""
+    _run_forced_8dev(SCRIPT_LOCAL_SOLVE.format(case=case))
+
+
+def test_pad_packed_width_inverse_solves_padding_to_exactly_zero():
+    """Widening a packing puts the identity on the new slots of the
+    stored inverse, so an rhs that is zero there solves to exactly 0
+    there (without the mask), and the padded solve still matches the
+    unpadded one."""
+    rng = np.random.default_rng(5)
+    obs = rng.beta(2, 5, 200)
+    prob = cls.local_problem(jax.random.PRNGKey(2), 64, obs)
+    dec = dd.decompose_1d(prob.n, dydd.dydd_1d(obs, 4).boundaries,
+                          overlap=1)
+    A, b, r = prob.stacked()
+    packed = ddkf.pack_operator(A, r, dec)
+    padded = ddkf.pad_packed_width(packed, packed.w + 5)
+    w = packed.w
+    Ninv = np.asarray(padded.Ninv_loc)
+    np.testing.assert_array_equal(Ninv[:, :w, :w],
+                                  np.asarray(packed.Ninv_loc))
+    np.testing.assert_array_equal(Ninv[:, w:, w:],
+                                  np.broadcast_to(np.eye(5), (dec.p, 5, 5)))
+    assert not Ninv[:, w:, :w].any() and not Ninv[:, :w, w:].any()
+
+    pad = np.asarray(padded.mask) == 0
+    assert pad[:, w:].all() and pad.sum() > 5 * dec.p
+    rhs = rng.normal(size=pad.shape) * np.asarray(padded.mask)
+    out = np.asarray(ddkf._local_solve(padded.Ninv_loc, jnp.asarray(rhs)))
+    assert (out[pad] == 0.0).all()
+    np.testing.assert_allclose(
+        out, np.einsum("pij,pj->pi", Ninv, rhs), rtol=1e-12, atol=1e-12)
+
+    x_pad = ddkf.solve_vmapped(ddkf.with_rhs(padded, b), iters=120)
+    x = ddkf.solve_vmapped(ddkf.with_rhs(packed, b), iters=120)
+    np.testing.assert_allclose(np.asarray(x_pad), np.asarray(x),
+                               rtol=1e-12, atol=1e-12)
+    assert float(jnp.linalg.norm(x_pad - cls.solve(prob))) < 1e-9

@@ -450,11 +450,11 @@ def test_gram_matches_ddkf_pack_normal_matrix():
     N = ops.gram(packed.A_loc.astype(jnp.float32),
                  jnp.tile(packed.r.astype(jnp.float32), (4, 1)),
                  mode="interpret", block_m=128)
-    # pack stores cholesky(N + pad-identity); reconstruct and compare
+    # pack stores inv(N + pad-identity): compare with the inverse of the
+    # kernel's normal matrix
     for i in range(4):
-        L = np.asarray(packed.L_loc[i], np.float64)
-        got = L @ L.T
+        got = np.asarray(packed.Ninv_loc[i], np.float64)
         k = int(np.asarray(packed.mask[i]).sum())
         want = np.asarray(N[i], np.float64)
         want[np.arange(k, packed.w), np.arange(k, packed.w)] += 1.0
-        np.testing.assert_allclose(got, want, atol=1e-3)
+        np.testing.assert_allclose(got, np.linalg.inv(want), atol=1e-3)

@@ -97,7 +97,7 @@ out["b"] = {f: {"bits": bool(np.array_equal(bits(getattr(one, f)),
                                             bits(getattr(four, f)))),
                 "sharded": getattr(four, f).sharding == sub,
                 "whole": getattr(four, f).sharding == whole}
-            for f in ("A_loc", "L_loc", "cols", "mask", "muov", "wdiv",
+            for f in ("A_loc", "Ninv_loc", "cols", "mask", "muov", "wdiv",
                       "mult", "mult_loc", "scatter_cols", "gather_cols",
                       "r", "b")}
 

@@ -576,7 +576,7 @@ def test_pack_operator_steps_leave_the_packing_unchanged(overlap):
     plain = ddkf.pack_operator(A, r, dec)
     assert set(phases) == {"pack.fill", "pack.h2d", "pack.factor"}
     assert all(v > 0 for v in phases.values())
-    for f in ("A_loc", "L_loc", "cols", "mask", "muov", "wdiv", "mult",
+    for f in ("A_loc", "Ninv_loc", "cols", "mask", "muov", "wdiv", "mult",
               "mult_loc", "scatter_cols", "gather_cols", "r", "b"):
         np.testing.assert_array_equal(np.asarray(getattr(timed, f)),
                                       np.asarray(getattr(plain, f)))

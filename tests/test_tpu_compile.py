@@ -14,6 +14,7 @@ import: only one process may load the TPU library at a time, and every
 test worker imports this file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -64,6 +65,13 @@ def _sds(sharding, *shape, dtype=F32):
 
 def _kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
+
+
+def _triangular_solves(compiled) -> list:
+    """The triangular-solve ops of a compiled program, or the custom
+    calls the chip's compiler expands one into."""
+    return re.findall(r"triangular-solve\(|custom_call_target=\"[^\"]*"
+                      r"Triangular", compiled.as_text())
 
 
 @pytest.mark.parametrize("w,block_m", _cases(ops.schwarz_tile_bytes))
@@ -159,7 +167,7 @@ def test_solve_shardmap_compiles_on_4_chips(topo, monkeypatch, comm):
     sub, rep = NamedSharding(mesh, P("sub")), NamedSharding(mesh, P())
     i32 = jnp.int32
     packed = ddkf.PackedDD(
-        A_loc=_sds(sub, p, M, w), L_loc=_sds(sub, p, w, w),
+        A_loc=_sds(sub, p, M, w), Ninv_loc=_sds(sub, p, w, w),
         cols=_sds(sub, p, w, dtype=i32), mask=_sds(sub, p, w),
         muov=_sds(sub, p, w), wdiv=_sds(sub, p, w), mult=_sds(rep, N),
         mult_loc=_sds(sub, p, w), scatter_cols=_sds(sub, p, w, dtype=i32),
@@ -182,6 +190,29 @@ def test_solve_shardmap_compiles_on_4_chips(topo, monkeypatch, comm):
     assert "all-reduce" in text
     if comm == "neighbour":
         assert "collective-permute" in text
+    # each sweep applies the stored inverse: no triangular solve
+    assert not _triangular_solves(compiled)
+
+
+def test_solve_vmapped_compiles_with_no_triangular_solve(one_chip,
+                                                        monkeypatch):
+    """The one-chip solve at Example 4's p = 8, w = 1070 (the width DyDD
+    gives the Beta(2, 5) network): the two fused kernels per sweep, and
+    the local solve a matvec against the stored inverse, with no
+    triangular solve (the chip's compiler expands one into
+    ``Invert*Triangular`` custom calls)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    w, i32 = 1070, jnp.int32
+    s = lambda *sh, dtype=F32: _sds(one_chip, *sh, dtype=dtype)
+    packed = ddkf.PackedDD(
+        A_loc=s(P8, M, w), Ninv_loc=s(P8, w, w), cols=s(P8, w, dtype=i32),
+        mask=s(P8, w), muov=s(P8, w), wdiv=s(P8, w), mult=s(N),
+        mult_loc=s(P8, w), scatter_cols=s(P8, w, dtype=i32),
+        gather_cols=s(P8, w, dtype=i32), r=s(M), b=s(M), n=N, p=P8, w=w,
+        solve_kernel="fused", solve_block=512)
+    compiled = ddkf.solve_vmapped.lower(packed, iters=60).compile()
+    assert _kernels(compiled) == 2          # schwarz_fwd + schwarz_bwd
+    assert not _triangular_solves(compiled)
 
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",

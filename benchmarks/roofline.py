@@ -4,8 +4,8 @@ prints the per-(arch x shape) three-term analysis — deliverable (g).
 ``--solve BENCH.json`` switches to the DD-KF solve roofline: from a
 streaming_bench report it rebuilds each arm's decomposition shapes,
 prices one Schwarz iteration per device as three terms — compute
-(~6mw + 2w^2 flops), memory (two HBM passes over the (m, w) operator
-block on the fused kernel, three on the jnp path) and collective (the
+(~6mw + 2w^2 flops), memory (the Schwarz sweep's two HBM passes over
+the (m, w) operator block) and collective (the
 m-vector all-reduce bytes from ``ddkf.comm_model`` under torus-aware
 mesh pricing) — and prints the modelled bound next to the measured
 solve-phase p50 from the report's journalled phase spans.
@@ -140,7 +140,7 @@ def solve_bound(meta: dict, config: dict, kernel: str,
     later; w only shrinks under balancing, so this is the conservative
     shape).  Per device and iteration: ~6mw + 2w^2 flops (two stacked
     matmats + the transpose product + the local inverse matvec), operator
-    bytes = passes * m * w * itemsize with passes = 2 fused / 3 jnp, and
+    bytes = 2 * m * w * itemsize (the sweep's two passes), and
     the collective term is comm_model's per-device pricing of the
     configured exchange under the domain's torus mesh shape.
     """
@@ -157,9 +157,8 @@ def solve_bound(meta: dict, config: dict, kernel: str,
     halo = dec.halo_exchange if comm == "neighbour" else None
     stats = ddkf.comm_model(dom.n, m, dom.p, itemsize, halo=halo,
                             comm=comm, mesh_shape=dom.mesh_axes()[1])
-    passes = 2 if kernel.startswith("fused") else 3
     flops = 6.0 * m * w + 2.0 * w * w
-    mem_bytes = passes * m * w * itemsize
+    mem_bytes = 2 * m * w * itemsize
     coll_bytes = stats["bytes_per_iter_total"] / dom.p \
         + stats["mvec_bytes_per_device"]
     terms = {
@@ -209,10 +208,6 @@ def print_solve_table(report: dict, peak_flops=PEAK_FLOPS,
                   f"{b['dominant']:>10s}")
             rows.append({"scenario": name, "arm": arm, "measured_s": meas,
                          **b})
-    fused = [r for r in rows if r["kernel"].startswith("fused")]
-    if fused:
-        print(f"\nfused kernel: modelled operator traffic 2/3 of the jnp "
-              f"path's (two HBM passes over A per iteration, not three)")
     return rows
 
 

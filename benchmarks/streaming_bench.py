@@ -14,8 +14,8 @@ pricing the allreduce vs neighbour (halo-only ppermute) state exchange
 across overlap widths s = 0..3, and — with ``--compare-comm`` on a
 sharded run — measured wall-clock for both paths side by side plus the
 max-abs difference of their final analyses (the ULP-parity evidence).
-``--compare-kernels`` does the same for the local Schwarz step: the
-historic jnp path vs the fused kernel (``solver_kernel=``), recording
+``--compare-kernels`` does the same for the Schwarz sweep's kernel: the
+jnp reference vs the fused kernel (``solver_kernel=``), recording
 solve-phase wall-clock shares and the final-analysis parity.
 
 ``--compare-domains`` additionally runs every 2D scenario's DyDD arm on
@@ -470,12 +470,10 @@ def main() -> None:
                 analyses["allreduce"] - analyses["neighbour"])))
             report["scenarios"][name]["comm_compare"] = compare
         if args.compare_kernels:
-            # Jnp-vs-fused Schwarz step on the same scenario: measured
+            # Jnp-vs-fused Schwarz sweep on the same scenario: measured
             # wall-clock and the solve phase's share of the cycle for
-            # both local-step implementations.  Off-TPU "fused" resolves
-            # to the single-pass stacked reference (same arithmetic
-            # structure as the kernel), so the comparison stays honest
-            # on a CPU CI host.
+            # both kernels.  Off-TPU "fused" resolves to the same jnp
+            # reference, so the two arms run one program there.
             kcompare = {}
             kanalyses = {}
             for kern in ("jnp", "fused"):

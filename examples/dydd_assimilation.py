@@ -169,9 +169,10 @@ def main() -> None:
                     "added to the loads the schedule balances")
     ap.add_argument("--solver-kernel", default="auto",
                     choices=ddkf.SOLVER_KERNELS,
-                    help="local Schwarz step: auto (fused Pallas on TPU, "
-                    "jnp elsewhere), jnp, fused, fused_interpret, "
-                    "fused_ref")
+                    help="what runs the Schwarz sweep: jnp (the jnp "
+                    "reference), fused (Pallas on TPU, the reference "
+                    "elsewhere), fused_interpret (Pallas in interpret "
+                    "mode) or auto (fused on TPU, jnp elsewhere)")
     ap.add_argument("--scenarios", nargs="*", default=None,
                     choices=streams.available(),
                     help="subset of the registered scenarios "

@@ -130,10 +130,13 @@ class EngineConfig:
                                       # inner loop to lax.scan; identical
                                       # numerics, one extra (iters,)
                                       # output per solve)
-    solver_kernel: str = "auto"       # local Schwarz step implementation:
-                                      # "auto" (fused Pallas on TPU, jnp
-                                      # elsewhere) | "jnp" | "fused" |
-                                      # "fused_interpret" | "fused_ref"
+    solver_kernel: str = "auto"       # what runs the one Schwarz sweep's
+                                      # two passes: "jnp" (the jnp
+                                      # reference) | "fused" (Pallas on
+                                      # TPU, the reference elsewhere) |
+                                      # "fused_interpret" (Pallas in
+                                      # interpret mode) | "auto" ("fused"
+                                      # on TPU, "jnp" elsewhere)
     solve_retries: int = 2            # bounded retry on a TransientFault
                                       # from prepare/solve (exponential
                                       # backoff); exceeding it is fatal.

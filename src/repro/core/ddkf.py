@@ -10,24 +10,25 @@ regularized VAR-KF problem (eq. 25/27) and the updates are assembled
 plus the boundary/overlap exchange folded into the assembly — exactly the
 communication structure the paper counts in its overhead T^p_oh.
 
-Two execution paths share the same step function:
-  * ``solve_vmapped``   — subdomains on the leading axis of a batch
-                          (single-device correctness/reference path);
-  * ``solve_shardmap``  — one device per subdomain on a 1D chain or a
-                          2D ``pr x pc`` grid mesh (the production path,
-                          exercised under forced multi-device XLA in
-                          tests and by the launch dry-run).  The m-vector
-                          all-reduce is a ``psum`` or — in the dense-
-                          network regime m >> n — a ``psum_scatter`` +
-                          ``all_gather`` pair; the overlap exchange is
-                          either the same reduce-scatter pair on the
-                          (n,) assembly (``comm="allreduce"``) or
-                          neighbour-only ``ppermute`` rounds of just the
-                          halo slots over the decomposition's coloured
-                          edge schedule (``comm="neighbour"`` — the
-                          paper's T^p_oh pattern: per-iteration traffic
-                          proportional to the overlap width s, not n).
-                          :func:`comm_model` prices both paths.
+Every layout runs one sweep, :func:`_schwarz_sweep`, over a leading axis
+of subdomains; the packing's ``solve_kernel`` picks how
+``repro.kernels.ops`` runs its two passes over the local blocks (Pallas,
+Pallas in interpret mode, or the jnp reference).  Only the reduction of
+Ax and the overlap exchange after the sweep depend on the layout:
+  * ``solve_vmapped`` — subdomains on a batch axis: Ax is a sum over it,
+    the exchange :func:`assemble` then :func:`gather_local`;
+  * ``solve_fleet`` — independent problems on a further leading axis,
+    each a ``solve_vmapped`` under ``lax.map``;
+  * ``solve_shardmap`` — one device per subdomain on a 1D chain or a 2D
+    ``pr x pc`` grid mesh.  Ax is a ``psum`` or — when m >> n — a
+    ``psum_scatter`` + ``all_gather`` pair; the exchange is the same
+    pair on the (n,) assembly (``comm="allreduce"``) or neighbour-only
+    ``ppermute`` rounds of just the halo slots (``comm="neighbour"`` —
+    the paper's T^p_oh pattern: traffic proportional to the overlap
+    width s, not n).  :func:`comm_model` prices both;
+  * ``solve_window_stack`` — windows x subdomains on a
+    ``("time", "sub")`` mesh: Ax sums the device's subdomains, then
+    ``psum``s over ``sub``; the sweep is vmapped over the windows.
 
 Static shapes: local blocks are padded to the max block width; padded
 columns carry an identity diagonal in the local normal matrix and zero
@@ -79,10 +80,10 @@ class PackedDD:
     n: int
     p: int
     w: int
-    solve_kernel: str = "jnp"   # resolved iteration-kernel path: "jnp" |
-                                # "fused" | "fused_interpret" | "fused_ref"
-    solve_block: int | None = None  # autotuned fused-kernel m-tile (None
-                                    # when the path has no blocking)
+    solve_kernel: str = "jnp"   # resolved Schwarz-step kernel: "jnp" |
+                                # "fused" | "fused_interpret"
+    solve_block: int | None = None  # autotuned kernel m-tile (None when
+                                    # the step runs the jnp reference)
 
     @property
     def m(self) -> int:
@@ -207,17 +208,15 @@ def pack(prob: cls_mod.CLSProblem, dec: dd_mod.Decomposition,
                                   solver_kernel=solver_kernel), b)
 
 
-# Iteration-kernel selection: how the per-iteration local step runs.
-# "jnp" is the historic composition (three HBM passes over A_loc per
-# iteration, bit-identical to every previous release); the "fused_*"
-# variants run the two-pass fused step of ``kernels/schwarz_step.py``
-# through the matching ops-mode ("fused" resolves per backend: the
-# native Pallas kernel on TPU, the single-pass stacked-matmat jnp
-# reference elsewhere; "fused_interpret" forces the kernel in interpret
-# mode — the CPU-CI ULP-parity path; "fused_ref" forces the reference).
-SOLVER_KERNELS = ("auto", "jnp", "fused", "fused_interpret", "fused_ref")
-_KERNEL_OPS_MODE = {"fused": "auto", "fused_interpret": "interpret",
-                    "fused_ref": "ref"}
+# Schwarz-step kernel selection: the ``repro.kernels.ops`` mode that runs
+# the sweep's two passes.  "jnp" is the jnp reference
+# (``kernels/ref.py``); "fused" resolves per backend (the native Pallas
+# kernel on TPU, the reference elsewhere and for float64);
+# "fused_interpret" forces the kernel in interpret mode — the CPU-CI
+# ULP-parity path.  "auto" picks "fused" on TPU and "jnp" elsewhere.
+SOLVER_KERNELS = ("auto", "jnp", "fused", "fused_interpret")
+_KERNEL_OPS_MODE = {"jnp": "ref", "fused": "auto",
+                    "fused_interpret": "interpret"}
 
 
 def _resolve_solver_kernel(solver_kernel: str) -> str:
@@ -225,9 +224,7 @@ def _resolve_solver_kernel(solver_kernel: str) -> str:
         raise ValueError(f"solver_kernel must be one of {SOLVER_KERNELS} "
                          f"(got {solver_kernel!r})")
     if solver_kernel == "auto":
-        # Default to the fused kernel only where it is a different (and
-        # faster) program: on TPU.  Elsewhere "auto" keeps the historic
-        # jnp composition so default numerics stay bit-identical.
+        # The Pallas kernel only where it runs natively: on TPU.
         return "fused" if jax.default_backend() == "tpu" else "jnp"
     return solver_kernel
 
@@ -340,7 +337,7 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
     reference elsewhere — see :mod:`repro.kernels.ops`).
     ``solver_kernel`` selects the per-iteration step path the solves will
     run (:data:`SOLVER_KERNELS`); it is resolved here, host-side — the
-    fused paths autotune their ``block_m`` once per shape
+    kernel paths autotune their ``block_m`` once per shape
     (``ops.schwarz_block_for``) and the choice rides along statically in
     the packing's meta fields.
 
@@ -426,9 +423,8 @@ def pack_operator(A: jax.Array, r: jax.Array, dec: dd_mod.Decomposition,
                                             gram_block=gram_block,
                                             mesh=mesh, axis=axis))
         solve_kernel = _resolve_solver_kernel(solver_kernel)
-        solve_block = (ops_mod.schwarz_block_for(
+        solve_block = ops_mod.schwarz_block_for(
             (p, m, w), A_loc.dtype, mode=_KERNEL_OPS_MODE[solve_kernel])
-            if solve_kernel != "jnp" else None)
     return PackedDD(A_loc=A_loc, Ninv_loc=Ninv_loc, r=r, n=n, p=p, w=w,
                     solve_kernel=solve_kernel, solve_block=solve_block,
                     **dev)
@@ -457,7 +453,7 @@ def pad_packed_width(packed: PackedDD, w_new: int) -> PackedDD:
     dump slot ``n`` — so the padded slots solve to exactly zero and the
     assembled estimate is unchanged up to reduction order.  This is a
     *tolerance-path* helper (the window-stacked Parareal fine solves):
-    widening changes the einsum reduction extents, so results agree with
+    widening changes the sweep's reduction extents, so results agree with
     the unpadded solve to ULPs, not bitwise.
     """
     if w_new < packed.w:
@@ -495,13 +491,28 @@ def _local_solve(Ninv, rhs):
     return jnp.sum(Ninv * rhs[..., None, :], axis=-1)
 
 
-def _local_update(A_i, Ninv_i, mask_i, muov_i, x_i, Ax, r, b):
-    """One local regularized VAR-KF solve given the global product Ax
-    (eq. 25/27): the mu-term anchors the overlap slots to the current
-    consistent global iterate x_i (= x_glob gathered)."""
-    resid = b - Ax + A_i @ x_i
-    rhs = (A_i.T @ (r * resid) + muov_i * x_i) * mask_i
-    return _local_solve(Ninv_i, rhs) * mask_i
+def _schwarz_sweep(A, Ninv, mask, muov, wdiv, x, r, b, damping, *,
+                   kernel: str, block_m: int | None, reduce_Ax):
+    """One damped additive-Schwarz sweep of a slice of subdomains.
+
+    ``A`` (ps, m, w), ``Ninv`` (ps, w, w) and the (ps, w) ``mask``,
+    ``muov``, ``wdiv`` and iterate ``x`` are the subdomains this caller
+    holds; ``r`` and ``b`` the (m,) weights and data.  Each subdomain
+    solves its local regularized VAR-KF problem (eq. 25/27) against the
+    global product Ax, the mu-term anchoring its overlap slots to the
+    current consistent iterate.  ``reduce_Ax`` turns the (ps, m) partial
+    products ``A_i (x_i * wdiv_i)`` into Ax — the one step that, with
+    the overlap exchange after the sweep, depends on the layout.
+    ``kernel`` (the packing's ``solve_kernel``) and ``block_m`` pick how
+    ``repro.kernels.ops`` runs the two passes over ``A``.
+    """
+    mode = _KERNEL_OPS_MODE[kernel]
+    y, u = ops_mod.schwarz_fwd(A, x, wdiv, mode=mode, block_m=block_m)
+    Ax = reduce_Ax(y)
+    rhs = ops_mod.schwarz_bwd(A, r, b, Ax, u, x, muov, mask, mode=mode,
+                              block_m=block_m)
+    new = _local_solve(Ninv, rhs) * mask
+    return (1.0 - damping) * x + damping * new
 
 
 # ---------------------------------------------------------------------------
@@ -529,37 +540,15 @@ def solve_vmapped(packed: PackedDD, iters: int = 60,
     in fewer iterations; ``x0=None`` keeps the historic zero start
     bitwise.
 
-    The per-iteration local step follows the packing's resolved
-    ``solve_kernel``: the historic jnp composition, or the fused
-    two-pass step of :mod:`repro.kernels.schwarz_step` (reduction-order
-    ULP parity with the jnp path).
+    Each iteration is one :func:`_schwarz_sweep` over all p subdomains,
+    run by the packing's resolved ``solve_kernel``.
     """
-    kern = packed.solve_kernel
-
     def step(x_loc):
-        if kern == "jnp":
-            # partition of unity: overlap columns contribute once to
-            # A x_glob
-            Ax_parts = jnp.einsum("pmw,pw->pm", packed.A_loc,
-                                  x_loc * packed.wdiv)
-            Ax = jnp.sum(Ax_parts, axis=0)
-            new = jax.vmap(
-                lambda A_i, N_i, m_i, mu_i, x_i: _local_update(
-                    A_i, N_i, m_i, mu_i, x_i, Ax, packed.r, packed.b)
-            )(packed.A_loc, packed.Ninv_loc, packed.mask, packed.muov,
-              x_loc)
-        else:
-            mode = _KERNEL_OPS_MODE[kern]
-            y, u = ops_mod.schwarz_fwd(packed.A_loc, x_loc, packed.wdiv,
-                                       mode=mode,
-                                       block_m=packed.solve_block)
-            Ax = jnp.sum(y, axis=0)
-            rhs = ops_mod.schwarz_bwd(packed.A_loc, packed.r, packed.b,
-                                      Ax, u, x_loc, packed.muov,
-                                      packed.mask, mode=mode,
-                                      block_m=packed.solve_block)
-            new = _local_solve(packed.Ninv_loc, rhs) * packed.mask
-        x_loc2 = (1.0 - damping) * x_loc + damping * new
+        x_loc2 = _schwarz_sweep(
+            packed.A_loc, packed.Ninv_loc, packed.mask, packed.muov,
+            packed.wdiv, x_loc, packed.r, packed.b, damping,
+            kernel=packed.solve_kernel, block_m=packed.solve_block,
+            reduce_Ax=lambda y: jnp.sum(y, axis=0))
         # Overlap consistency: average duplicated columns globally, then
         # gather back (eq. 28).
         x_glob = assemble(packed, x_loc2)
@@ -641,19 +630,10 @@ def _stack_jit(packs):
 
 
 @partial(jax.jit, static_argnames=("iters", "residual_history"))
-def _solve_fleet_map(stacked: PackedDD, iters: int, damping,
+def _solve_fleet_map(stacked: PackedDD, x0, damping, iters: int,
                      residual_history: bool):
-    return jax.lax.map(
-        lambda pk: solve_vmapped(pk, iters=iters, damping=damping,
-                                 residual_history=residual_history),
-        stacked)
-
-
-@partial(jax.jit, static_argnames=("iters", "residual_history"))
-def _solve_fleet_map_warm(stacked: PackedDD, x0, iters: int, damping,
-                          residual_history: bool):
-    # Separate jit from the cold path so x0=None callers keep their
-    # historic trace (and bitwise output) untouched.
+    """``solve_vmapped`` of every problem of the stack, one after the
+    other, each from its (n,) start in ``x0``."""
     return jax.lax.map(
         lambda arg: solve_vmapped(arg[0], iters=iters, damping=damping,
                                   residual_history=residual_history,
@@ -669,16 +649,11 @@ def _fleet_sharded_fn(mesh, axis: str, iters: int, residual_history: bool):
     fn = _FLEET_SHARDED_CACHE.get(key)
     if fn is not None:
         return fn
-
-    def body(pk, damping):
-        return jax.lax.map(
-            lambda q: solve_vmapped(q, iters=iters, damping=damping,
-                                    residual_history=residual_history),
-            pk)
-
+    body = partial(_solve_fleet_map, iters=iters,
+                   residual_history=residual_history)
     fn = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(axis),
-        check_vma=False))
+        body, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+        out_specs=P(axis), check_vma=False))
     _FLEET_SHARDED_CACHE[key] = fn
     return fn
 
@@ -706,29 +681,24 @@ def solve_fleet(stacked: PackedDD, iters: int = 60, damping: float = 1.0,
     Returns the (S, n) stacked estimates, or ``(x, hist)`` with ``hist``
     of shape (S, iters) under ``residual_history=True``.
 
-    ``x0`` (single-device path only) is an optional (S, n) stack of
-    global warm starts, one per problem — see :func:`solve_vmapped`.
+    ``x0`` is an optional (S, n) stack of global warm starts, one per
+    problem — see :func:`solve_vmapped`.  None starts every problem from
+    zeros, which gathers to exactly the zero start of ``x0=None``.
     """
-    if mesh is None:
-        if x0 is not None:
-            return _solve_fleet_map_warm(
-                stacked, jnp.asarray(x0, stacked.A_loc.dtype),
-                iters=iters, damping=damping,
-                residual_history=residual_history)
-        return _solve_fleet_map(stacked, iters=iters, damping=damping,
-                                residual_history=residual_history)
-    if x0 is not None:
-        raise NotImplementedError(
-            "solve_fleet warm start is single-device only (the sharded "
-            "fleet path has no x0 plumbing)")
-    k = int(mesh.shape[axis])
+    dt = stacked.A_loc.dtype
     S = int(stacked.A_loc.shape[0])
+    x0 = (jnp.zeros((S, stacked.n), dt) if x0 is None
+          else jnp.asarray(x0, dt))
+    if mesh is None:
+        return _solve_fleet_map(stacked, x0, damping, iters=iters,
+                                residual_history=residual_history)
+    k = int(mesh.shape[axis])
     if S % k:
         raise ValueError(
             f"cohort size {S} does not divide over the {k}-device "
             f"'{axis}' mesh axis — pad the cohort to a multiple of {k}")
     fn = _fleet_sharded_fn(mesh, axis, iters, residual_history)
-    return fn(stacked, damping)
+    return fn(stacked, x0, damping)
 
 
 # ---------------------------------------------------------------------------
@@ -860,7 +830,7 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
         return jax.lax.all_gather(chunk, axes[-1], tiled=True)
 
     def _solve_shard_map(packed, pack_idx, unpack_idx, damping):
-        n, m, w, kern = packed.n, packed.m, packed.w, packed.solve_kernel
+        n, m, w = packed.n, packed.m, packed.w
         n_pad = -(-(n + 1) // ks) * ks
         m_pad = -(-m // ks) * ks
 
@@ -918,22 +888,15 @@ def _shardmap_fn(mesh, axes: tuple, iters: int, comm: str, mvec: str,
                         else exchange_allreduce)
 
             def step(x_i):
-                if kern == "jnp":
-                    Ax = mvec_allreduce(A_i @ (x_i * wdiv_i))
-                    new = _local_update(A_i, N_i, mask_i, muov_i, x_i, Ax,
-                                        r, b)
-                else:
-                    mode = _KERNEL_OPS_MODE[kern]
-                    y, u = ops_mod.schwarz_fwd(A_i[None], x_i[None],
-                                               wdiv_i[None], mode=mode,
-                                               block_m=packed.solve_block)
-                    Ax = mvec_allreduce(y[0])
-                    rhs = ops_mod.schwarz_bwd(A_i[None], r, b, Ax, u,
-                                              x_i[None], muov_i[None],
-                                              mask_i[None], mode=mode,
-                                              block_m=packed.solve_block)[0]
-                    new = _local_solve(N_i, rhs) * mask_i
-                return exchange((1.0 - damping) * x_i + damping * new)
+                # A slice of one subdomain.  (Re-adding the axis to the
+                # stripped blocks, not passing them as they came, keeps
+                # N_i in the (w, w) layout with no copy per solve.)
+                x_i2 = _schwarz_sweep(
+                    A_i[None], N_i[None], mask_i[None], muov_i[None],
+                    wdiv_i[None], x_i[None], r, b, damping,
+                    kernel=packed.solve_kernel, block_m=packed.solve_block,
+                    reduce_Ax=lambda y: mvec_allreduce(y[0]))
+                return exchange(x_i2[0])
 
             x_i = jnp.zeros((w,), dtype=A_i.dtype)
             if residual_history:
@@ -983,11 +946,11 @@ _SHARDMAP_CACHE: dict = {}
 # ---------------------------------------------------------------------------
 
 def _window_sharded_fn(mesh, time_axis: str, sub_axis: str, iters: int,
-                       n: int):
+                       n: int, kernel: str, block_m: int | None):
     """Jitted shard_map of the window-stacked Schwarz sweep (cached per
-    (mesh, axes, iters, n) — mesh objects hash; shapes recompile under
-    jit as usual)."""
-    key = (mesh, time_axis, sub_axis, iters, n)
+    (mesh, axes, iters, n, kernel, block_m) — mesh objects hash; shapes
+    recompile under jit as usual)."""
+    key = (mesh, time_axis, sub_axis, iters, n, kernel, block_m)
     fn = _WINDOW_SHARDED_CACHE.get(key)
     if fn is not None:
         return fn
@@ -1016,19 +979,17 @@ def _window_sharded_fn(mesh, time_axis: str, sub_axis: str, iters: int,
                                       tiled=True)   # (Kl, n_pad)
             return glob[:, :n] / mult               # (Kl, n)
 
+        def sweep(A, Ninv, mask, muov, wdiv, x, r, b):
+            # One window: this device's pl subdomains, Ax summed over
+            # them and then over ``sub``.
+            return _schwarz_sweep(
+                A, Ninv, mask, muov, wdiv, x, r, b, damping, kernel=kernel,
+                block_m=block_m,
+                reduce_Ax=lambda y: jax.lax.psum(jnp.sum(y, axis=0),
+                                                 sub_axis))
+
         def step(x):
-            # One additive-Schwarz iteration per window, batched over
-            # this device's (Kl, pl) slice — the jnp composition of
-            # solve_vmapped (fused-kernel packings ride this path too;
-            # the two steps agree to reduction-order ULPs).
-            Ax = jax.lax.psum(
-                jnp.einsum("kpmw,kpw->km", A, x * wdiv), sub_axis)
-            resid = (b[:, None, :] - Ax[:, None, :]
-                     + jnp.einsum("kpmw,kpw->kpm", A, x))
-            rhs = (jnp.einsum("kpmw,kpm->kpw", A, r[:, None, :] * resid)
-                   + muov * x) * mask
-            new = _local_solve(Ninv, rhs) * mask
-            x2 = (1.0 - damping) * x + damping * new
+            x2 = jax.vmap(sweep)(A, Ninv, mask, muov, wdiv, x, r, b)
             x_glob = assemble_glob(x2)
             return jax.vmap(lambda xg, g: xg[g])(x_glob, gath) * mask
 
@@ -1068,11 +1029,12 @@ def solve_window_stack(stacked: PackedDD, mesh, time_axis: str = "time",
     windows never communicate, which is what makes the time axis free
     parallelism.
 
-    The iteration is the jnp additive-Schwarz composition of
-    :func:`solve_vmapped` with allreduce state exchange — per-window
-    results agree with standalone ``solve_vmapped`` calls to
-    reduction-order ULPs (a tolerance contract; the Parareal driver's
-    bitwise degeneration path never reaches this function).
+    Each iteration is one :func:`_schwarz_sweep` per window, run by the
+    packing's ``solve_kernel`` as in :func:`solve_vmapped`, with
+    allreduce state exchange — per-window results agree with standalone
+    ``solve_vmapped`` calls to reduction-order ULPs (a tolerance
+    contract; the Parareal engine's bitwise degeneration path never
+    reaches this function).
 
     ``x0`` is an optional (K, n) stack of global warm starts, one per
     window — see :func:`solve_vmapped`.  None starts from zeros (the
@@ -1091,7 +1053,8 @@ def solve_window_stack(stacked: PackedDD, mesh, time_axis: str = "time",
         raise ValueError(
             f"p={stacked.p} subdomains do not divide over the "
             f"{ks}-device '{sub_axis}' mesh axis")
-    fn = _window_sharded_fn(mesh, time_axis, sub_axis, iters, stacked.n)
+    fn = _window_sharded_fn(mesh, time_axis, sub_axis, iters, stacked.n,
+                            stacked.solve_kernel, stacked.solve_block)
     dt = stacked.A_loc.dtype
     x0 = (jnp.zeros((K, stacked.n), dt) if x0 is None
           else jnp.asarray(x0, dt))

@@ -7,10 +7,11 @@ Each solver iteration applies, per subdomain i (paper eqs. 23-26):
     resid  = b - Ax + A_i @ x_i            # local residual correction
     rhs_i  = (A_i^T @ (r * resid) + muov_i * x_i) * mask_i
 
-The jnp composition reads the (m x w) local operator A_i from HBM three
-times per iteration (two forward matvecs + one transposed reduction) and
-materializes ``resid`` as an (m,) HBM round-trip.  The fused kernels cut
-that to one double-pass with no resid materialization:
+Written as three separate products, the step reads the (m x w) local
+operator A_i from HBM three times per iteration (two forward matvecs +
+one transposed reduction) and materializes ``resid`` as an (m,) HBM
+round-trip.  The fused kernels cut that to two passes with no resid
+materialization:
 
 * :func:`schwarz_fwd` — ONE pass over A_i tiles computes both forward
   products as a single stacked (2, w) x (w, bm) MXU matmul per tile

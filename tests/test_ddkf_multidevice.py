@@ -452,11 +452,19 @@ prob = cls.local_problem(jax.random.PRNGKey(0), 128, obs)
 x_direct = np.asarray(cls.solve(prob))
 res = dydd.dydd_1d(obs, 8)
 dec = dd.decompose_1d(prob.n, res.boundaries, overlap=2)
-kernel = "fused_ref" if case == "vmapped_fused_ref" else "jnp"
-packed = ddkf.pack(prob, dec, solver_kernel=kernel)
-if case.startswith("vmapped"):
+packed = ddkf.pack(prob, dec, solver_kernel="jnp")
+x_directs = [x_direct]
+if case == "vmapped_jnp":
     solve = lambda pk: ddkf.solve_vmapped(pk, iters=120)
     xs = [solve(packed)]
+elif case == "fleet":
+    # two problems on one operator (other data), stacked
+    prob2 = cls.local_problem(jax.random.PRNGKey(1), 128, obs)
+    x_directs.append(np.asarray(cls.solve(prob2)))
+    packed = ddkf.stack_packed([packed, ddkf.pack(prob2, dec,
+                                                  solver_kernel="jnp")])
+    solve = lambda pk: ddkf.solve_fleet(pk, iters=120)
+    xs = list(solve(packed))
 elif case == "shardmap":
     mesh = jax.make_mesh((8,), ("sub",),
                          axis_types=(jax.sharding.AxisType.Auto,))
@@ -472,26 +480,63 @@ else:
     packed = ddkf.stack_packed([packed, packed])
     solve = lambda pk: ddkf.solve_window_stack(pk, mesh, iters=120)
     xs = list(solve(packed))
+    x_directs.append(x_direct)
 # A triangular solve lowers to the HLO op triangular-solve, or on the CPU
 # to a LAPACK trsm custom call named after the primitive.
 hlo = jax.jit(solve).lower(packed).as_text(dialect="hlo")
 assert not re.search(r"triangular|trsm", hlo, re.I), case
 assert "dot(" in hlo, case
-for x in xs:
-    err = float(np.linalg.norm(np.asarray(x) - x_direct))
+assert len(xs) == len(x_directs), case
+for x, xd in zip(xs, x_directs):
+    err = float(np.linalg.norm(np.asarray(x) - xd))
     assert err < 1e-9, (case, err)
 print("OK", case, err)
 """
 
 
-@pytest.mark.parametrize("case", ["vmapped_jnp", "vmapped_fused_ref",
-                                  "shardmap", "window_stack"])
+@pytest.mark.parametrize("case", ["vmapped_jnp", "fleet", "shardmap",
+                                  "window_stack"])
 def test_solves_apply_the_inverse_with_no_triangular_solve(case):
     """Every layout of the Schwarz step applies the packing's stored
     inverse as a matvec: the lowered solve holds no triangular solve,
     and the estimate matches the direct CLS solve as the shard_map
     equivalence test requires."""
     _run_forced_8dev(SCRIPT_LOCAL_SOLVE.format(case=case))
+
+
+SCRIPT_WINDOW_KERNEL = r"""
+import jax
+jax.config.update("jax_enable_x64", True)
+import numpy as np
+from repro.core import cls, dd, ddkf, dydd
+
+rng = np.random.default_rng(0)
+obs = rng.beta(2, 5, size=400)
+dec = dd.decompose_1d(128, dydd.dydd_1d(obs, 8).boundaries, overlap=2)
+packs = [ddkf.pack(cls.local_problem(jax.random.PRNGKey(k), 128, obs), dec,
+                   solver_kernel="fused_interpret") for k in (0, 1)]
+assert all(pk.solve_kernel == "fused_interpret" for pk in packs)
+# two windows over ("time": 2, "sub": 4): two subdomains per device
+mesh = jax.make_mesh((2, 4), ("time", "sub"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+stacked = ddkf.stack_packed(packs)
+solve = lambda pk: ddkf.solve_window_stack(pk, mesh, iters=60, damping=0.7)
+# the sweep's two passes are the Pallas kernels
+assert str(jax.make_jaxpr(solve)(stacked)).count("pallas_call") == 2
+xs = solve(stacked)
+for pk, x in zip(packs, np.asarray(xs)):
+    x_v = np.asarray(ddkf.solve_vmapped(pk, iters=60, damping=0.7))
+    d = float(np.abs(x - x_v).max())
+    assert d < 1e-13, d
+print("OK", d)
+"""
+
+
+def test_window_stack_runs_the_packings_kernel():
+    """The window-stacked solve runs the packing's Schwarz-step kernel
+    (here the Pallas kernel in interpret mode) and matches per-window
+    ``solve_vmapped`` on the same packings to reduction-order ULPs."""
+    _run_forced_8dev(SCRIPT_WINDOW_KERNEL)
 
 
 def test_pad_packed_width_inverse_solves_padding_to_exactly_zero():

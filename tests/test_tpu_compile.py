@@ -215,6 +215,34 @@ def test_solve_vmapped_compiles_with_no_triangular_solve(one_chip,
     assert not _triangular_solves(compiled)
 
 
+def test_solve_window_stack_compiles_with_the_fused_kernels(topo,
+                                                           monkeypatch):
+    """The window-stacked Parareal solve on a (2, 2) ("time", "sub")
+    mesh of the described chips, two windows of Example 4's p = 4,
+    overlap-1 packing (w = 1257): each device's sweep runs the two fused
+    kernels over its windows and subdomains, with no triangular
+    solve."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]).reshape(2, 2),
+                             ("time", "sub"))
+    ws = NamedSharding(mesh, P("time", "sub"))
+    wt = NamedSharding(mesh, P("time"))
+    K, p, w, i32 = 2, 4, 1257, jnp.int32
+    stacked = ddkf.PackedDD(
+        A_loc=_sds(ws, K, p, M, w), Ninv_loc=_sds(ws, K, p, w, w),
+        cols=_sds(ws, K, p, w, dtype=i32), mask=_sds(ws, K, p, w),
+        muov=_sds(ws, K, p, w), wdiv=_sds(ws, K, p, w),
+        mult=_sds(wt, K, N), mult_loc=_sds(ws, K, p, w),
+        scatter_cols=_sds(ws, K, p, w, dtype=i32),
+        gather_cols=_sds(ws, K, p, w, dtype=i32), r=_sds(wt, K, M),
+        b=_sds(wt, K, M), n=N, p=p, w=w, solve_kernel="fused",
+        solve_block=512)
+    compiled = jax.jit(lambda pk: ddkf.solve_window_stack(
+        pk, mesh, iters=60)).lower(stacked).compile()
+    assert _kernels(compiled) == 2          # schwarz_fwd + schwarz_bwd
+    assert not _triangular_solves(compiled)
+
+
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "all-to-all")
 

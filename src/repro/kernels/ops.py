@@ -206,16 +206,20 @@ def autotune_schwarz_block(p: int, m: int, w: int, dtype,
     """Pick block_m for a (p, m, w) fused Schwarz step by timing the
     candidates once (one warmup + one timed launch of fwd+bwd each).
     Cached per (shape, dtype, path); over-VMEM candidates are rejected
-    without being timed, keeping at least the narrowest."""
-    if not interpret:
-        w = w + (-w % 128)
+    without being timed, keeping at least the narrowest.
+
+    Both paths time the raw width w, the shape the solve runs (the
+    kernels read A at its own width, see :func:`schwarz_fwd`); the VMEM
+    model prices the lane-rounded width, since VMEM tiles are 128 lanes
+    wide."""
     key = (int(p), int(m), int(w), jnp.dtype(dtype).name, bool(interpret))
     hit = _SCHWARZ_TUNE_CACHE.get(key)
     if hit is not None:
         return hit["block_m"]
+    w_vmem = w + (-w % 128)
     candidates = sorted({min(c, m) for c in SCHWARZ_BLOCK_CANDIDATES})
-    rejected = {bm: schwarz_tile_bytes(bm, w) for bm in candidates
-                if schwarz_tile_bytes(bm, w) > GRAM_VMEM_BUDGET_BYTES}
+    rejected = {bm: schwarz_tile_bytes(bm, w_vmem) for bm in candidates
+                if schwarz_tile_bytes(bm, w_vmem) > GRAM_VMEM_BUDGET_BYTES}
     kept = [bm for bm in candidates if bm not in rejected]
     if not kept:
         kept = candidates[:1]
@@ -313,9 +317,9 @@ def schwarz_fwd(A, x, wdiv, *, mode: str = "auto",
     one pass over A.  A: (p, m, w), x/wdiv: (p, w).
 
     float64 takes the jnp reference under mode="auto" (still single-pass
-    — the reference uses the same stacked matmat); the native kernel
-    pads the lane (w) axis to 128 with zero columns (extra columns
-    contribute nothing to either product)."""
+    — the reference uses the same stacked matmat).  The native kernel
+    reads A at its own width w: a block may span a dimension's full
+    extent at any w, so no lane pad (a copy of A per call) is made."""
     m = _resolve(mode)
     if m == "ref" or (mode == "auto" and A.dtype == jnp.float64):
         return _ref.schwarz_fwd_ref(A, x, wdiv)
@@ -326,14 +330,6 @@ def schwarz_fwd(A, x, wdiv, *, mode: str = "auto",
             p, mm, w_ = A.shape
             block_m = autotune_schwarz_block(p, mm, w_, A.dtype,
                                              interpret=(m == "interpret"))
-    w = A.shape[-1]
-    wpad = -w % 128
-    if m == "kernel" and wpad:
-        A = jnp.pad(A, ((0, 0), (0, 0), (0, wpad)))
-        x = jnp.pad(x, ((0, 0), (0, wpad)))
-        wdiv = jnp.pad(wdiv, ((0, 0), (0, wpad)))
-        return _sch.schwarz_fwd(A, x, wdiv, block_m=block_m,
-                                interpret=False)
     return _sch.schwarz_fwd(A, x, wdiv, block_m=block_m,
                             interpret=(m == "interpret"))
 
@@ -342,7 +338,8 @@ def schwarz_bwd(A, r, b, Ax, u, x, muov, mask, *, mode: str = "auto",
                 block_m: int | None = None):
     """Fused backward Schwarz half: rhs = (A^T @ (r * (b - Ax + u)) +
     muov * x) * mask in one pass over A with VMEM-resident residual
-    tiles.  A: (p, m, w), r/b/Ax: (m,), u: (p, m), rest (p, w)."""
+    tiles.  A: (p, m, w), r/b/Ax: (m,), u: (p, m), rest (p, w); like
+    :func:`schwarz_fwd`, the native kernel reads A at its own width."""
     m = _resolve(mode)
     if m == "ref" or (mode == "auto" and A.dtype == jnp.float64):
         return _ref.schwarz_bwd_ref(A, r, b, Ax, u, x, muov, mask)
@@ -353,15 +350,6 @@ def schwarz_bwd(A, r, b, Ax, u, x, muov, mask, *, mode: str = "auto",
             p, mm, w_ = A.shape
             block_m = autotune_schwarz_block(p, mm, w_, A.dtype,
                                              interpret=(m == "interpret"))
-    w = A.shape[-1]
-    wpad = -w % 128
-    if m == "kernel" and wpad:
-        pad2 = ((0, 0), (0, wpad))
-        out = _sch.schwarz_bwd(
-            jnp.pad(A, ((0, 0), (0, 0), (0, wpad))), r, b, Ax, u,
-            jnp.pad(x, pad2), jnp.pad(muov, pad2), jnp.pad(mask, pad2),
-            block_m=block_m, interpret=False)
-        return out[:, :w]
     return _sch.schwarz_bwd(A, r, b, Ax, u, x, muov, mask,
                             block_m=block_m, interpret=(m == "interpret"))
 

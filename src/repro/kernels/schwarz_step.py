@@ -25,12 +25,14 @@ materialization:
   ``+ muov * x`` / ``* mask`` epilogue runs once at the last m-block.
 
 TPU mapping mirrors ``gram.py``: grid (p, m/bm) with the m axis
-sequential, accumulator in VMEM scratch, lane (w) axis padded to 128 by
-the wrapper in ops.py, and ``bm`` a multiple of 128 (or all of m) since
-it is the lane axis of the m-vector tiles.  Unlike gram, f64 inputs keep
-an f64 accumulator (interpret mode must stay ULP-comparable to the jnp
-path; the f32 accumulator is only used for f32/bf16 inputs where the MXU
-accumulates in f32 anyway).
+sequential, accumulator in VMEM scratch, and ``bm`` a multiple of 128
+(or all of m) since it is the lane axis of the m-vector tiles.  Every
+block spans the whole lane (w) axis, so w need not be a multiple of 128:
+the kernels read A at its own width, with no padded copy (HBM already
+stores the array in (8, 128) tiles, so the DMAs move the same tiles).
+Unlike gram, f64 inputs keep an f64 accumulator (interpret mode must
+stay ULP-comparable to the jnp path; the f32 accumulator is only used
+for f32/bf16 inputs where the MXU accumulates in f32 anyway).
 """
 from __future__ import annotations
 

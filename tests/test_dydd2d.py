@@ -1,5 +1,7 @@
 """2D DyDD — the paper's Ω ⊂ R² setting (Figures 1-4) — plus the gram
 kernel (DD-KF normal-matrix hot spot)."""
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -438,6 +440,49 @@ def test_schwarz_autotune_rejects_over_vmem_candidates(monkeypatch):
     b2 = ops.autotune_schwarz_block(2, 640, 24, jnp.float32,
                                     interpret=True)
     assert b2 == min(min(c, 640) for c in ops.SCHWARZ_BLOCK_CANDIDATES)
+
+
+def test_schwarz_autotune_times_the_raw_width_on_the_native_path(
+        monkeypatch):
+    """The native kernels read A at its own width, so the native path's
+    autotune times that width, and its cache key and ``schwarz.autotune``
+    event (the journal's record of the width the kernels run) report it;
+    the VMEM model still prices the lane-rounded width, since VMEM tiles
+    are 128 lanes wide.  The native launches run in interpret mode
+    here."""
+    from repro.obs import meters as meters_mod
+    sch = ops._sch
+    monkeypatch.setattr(ops, "_sch", types.SimpleNamespace(
+        schwarz_fwd=lambda *a, **k: sch.schwarz_fwd(
+            *a, **{**k, "interpret": True}),
+        schwarz_bwd=lambda *a, **k: sch.schwarz_bwd(
+            *a, **{**k, "interpret": True})))
+    monkeypatch.setattr(ops, "_SCHWARZ_TUNE_CACHE", {})
+    p, m, w = 2, 640, 20
+    # every candidate fits at 20 lanes; at 128, 512 and 640 do not
+    budget = (ops.schwarz_tile_bytes(256, 128)
+              + ops.schwarz_tile_bytes(512, 128)) // 2
+    assert ops.schwarz_tile_bytes(640, w) < budget
+    monkeypatch.setattr(ops, "GRAM_VMEM_BUDGET_BYTES", budget)
+    prev = meters_mod.set_meters(None)
+    try:
+        b = ops.autotune_schwarz_block(p, m, w, jnp.float32)
+        events = [e for e in meters_mod.get_meters().events
+                  if e["name"] == "schwarz.autotune"]
+    finally:
+        meters_mod.set_meters(prev)
+    report = ops.schwarz_tuning_report()
+    assert list(report) == ["p2_m640_w20_float32"]
+    entry = report["p2_m640_w20_float32"]
+    assert entry["block_m"] == b in (128, 256)
+    assert set(entry["sweep_s"]) == {128, 256}
+    assert entry["rejected_vmem"] == {
+        str(bm): ops.schwarz_tile_bytes(bm, 128) for bm in (512, 640)}
+    [event] = events
+    assert event["shape"] == [p, m, w]
+    assert event["block_m"] == b
+    assert event["candidates"] == [128, 256]
+    assert event["rejected_vmem"] == [512, 640]
 
 
 def test_gram_matches_ddkf_pack_normal_matrix():

@@ -50,13 +50,21 @@ def one_chip(topo):
 
 def _kept(tile_bytes, w):
     """The block_m candidates the autotune times at width w (the rest it
-    rejects on its VMEM model without compiling them)."""
+    rejects on its VMEM model, which prices the lane-rounded width,
+    without compiling them)."""
+    w_vmem = w + (-w % 128)
     return [bm for bm in ops.GRAM_BLOCK_CANDIDATES
-            if tile_bytes(bm, w) <= ops.GRAM_VMEM_BUDGET_BYTES]
+            if tile_bytes(bm, w_vmem) <= ops.GRAM_VMEM_BUDGET_BYTES]
 
 
-def _cases(tile_bytes):
-    return [(w, bm) for w in (256, 1664) for bm in _kept(tile_bytes, w)]
+def _cases(tile_bytes, widths=(256, 1664)):
+    return [(w, bm) for w in widths for bm in _kept(tile_bytes, w)]
+
+
+# The Schwarz kernels read A at its own width, so they also compile at
+# the widths DyDD gives Example 4 on the Beta(2, 5) network, which are
+# no multiple of 128: w = 1070 (p = 8) and w = 1257 (p = 4, overlap 1).
+SCHWARZ_WIDTHS = (256, 1070, 1257, 1664)
 
 
 def _sds(sharding, *shape, dtype=F32):
@@ -74,7 +82,8 @@ def _triangular_solves(compiled) -> list:
                       r"Triangular", compiled.as_text())
 
 
-@pytest.mark.parametrize("w,block_m", _cases(ops.schwarz_tile_bytes))
+@pytest.mark.parametrize("w,block_m", _cases(ops.schwarz_tile_bytes,
+                                              SCHWARZ_WIDTHS))
 def test_schwarz_fwd_compiles(one_chip, w, block_m):
     s = lambda *sh: _sds(one_chip, *sh)
     c = jax.jit(lambda A, x, wd: schwarz_step.schwarz_fwd(
@@ -83,7 +92,8 @@ def test_schwarz_fwd_compiles(one_chip, w, block_m):
     assert _kernels(c) == 1
 
 
-@pytest.mark.parametrize("w,block_m", _cases(ops.schwarz_tile_bytes))
+@pytest.mark.parametrize("w,block_m", _cases(ops.schwarz_tile_bytes,
+                                              SCHWARZ_WIDTHS))
 def test_schwarz_bwd_compiles(one_chip, w, block_m):
     s = lambda *sh: _sds(one_chip, *sh)
     c = jax.jit(lambda A, r, b, ax, u, x, mu, mk: schwarz_step.schwarz_bwd(
@@ -112,8 +122,11 @@ def test_every_candidate_fits_example4_uniform_width():
 
 @pytest.mark.parametrize("kernel", ["schwarz_fwd", "schwarz_bwd", "gram"])
 def test_ops_pads_unaligned_width(one_chip, kernel):
-    """w = 250 is no multiple of 128: ``ops`` pads the lane axis to 256
-    for the native kernel and slices the result back."""
+    """w = 250 is no multiple of 128.  The Schwarz kernels read A at its
+    own width: the compiled program runs the kernel and holds no
+    lane-padded (p, m, 256) copy of A.  ``ops.gram`` still pads the lane
+    axis to 256 for the native kernel (once per packing, outside the
+    solve) and slices the result back."""
     w = 250
     s = lambda *sh: _sds(one_chip, *sh)
     if kernel == "schwarz_fwd":
@@ -132,7 +145,10 @@ def test_ops_pads_unaligned_width(one_chip, kernel):
     lowered = jax.jit(fn).lower(*args)
     got = [o.shape for o in jax.tree.leaves(lowered.out_info)]
     assert got == want
-    assert _kernels(lowered.compile()) == 1
+    compiled = lowered.compile()
+    assert _kernels(compiled) == 1
+    padded_A = f"f32[{P8},{M},256]" in compiled.as_text()
+    assert padded_A == (kernel == "gram")
 
 
 def test_block_gather_compiles_at_example4_width(one_chip):
@@ -274,3 +290,60 @@ def test_sharded_pack_compiles_on_4_chips(topo, monkeypatch):
     # one block, not four, up to its tile padding
     assert M * w * 4 <= mem.output_size_in_bytes < 1.1 * M * w * 4
     assert mem.temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("layout", ["vmapped", "allreduce", "neighbour"])
+def test_solve_reads_A_loc_at_its_own_width(topo, one_chip, monkeypatch,
+                                            layout):
+    """The solves at the widths DyDD gives Example 4 on the Beta(2, 5)
+    network, which are no multiple of 128: ``solve_vmapped`` at p = 8,
+    w = 1070 on one chip, and the sharded solve (both ``comm``) at p = 4,
+    overlap 1, w = 1257 on a (4,) mesh.  The kernels read A_loc as it
+    stands: no op has its lane-rounded shape (1152 / 1280 lanes, which
+    a lane pad inside the loop would copy on every sweep), and the
+    one-chip solve's temporaries hold at most one copy of A_loc."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    i32 = jnp.int32
+    if layout == "vmapped":
+        p, w = P8, 1070
+        s = lambda *sh, dtype=F32: _sds(one_chip, *sh, dtype=dtype)
+        packed = ddkf.PackedDD(
+            A_loc=s(p, M, w), Ninv_loc=s(p, w, w), cols=s(p, w, dtype=i32),
+            mask=s(p, w), muov=s(p, w), wdiv=s(p, w), mult=s(N),
+            mult_loc=s(p, w), scatter_cols=s(p, w, dtype=i32),
+            gather_cols=s(p, w, dtype=i32), r=s(M), b=s(M), n=N, p=p, w=w,
+            solve_kernel="fused", solve_block=512)
+        compiled = ddkf.solve_vmapped.lower(packed, iters=60).compile()
+        padded = f"f32[{p},{M},1152]"
+    else:
+        mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("sub",))
+        # the widest block, 1255 columns and one overlap column each side
+        dec = dd.decompose_1d(N, [0.0, 0.125, 0.738, 0.87, 1.0], overlap=1)
+        halo = dec.halo_exchange
+        p, w = dec.p, halo.w
+        assert w == 1257
+        sub, rep = NamedSharding(mesh, P("sub")), NamedSharding(mesh, P())
+        packed = ddkf.PackedDD(
+            A_loc=_sds(sub, p, M, w), Ninv_loc=_sds(sub, p, w, w),
+            cols=_sds(sub, p, w, dtype=i32), mask=_sds(sub, p, w),
+            muov=_sds(sub, p, w), wdiv=_sds(sub, p, w), mult=_sds(rep, N),
+            mult_loc=_sds(sub, p, w),
+            scatter_cols=_sds(sub, p, w, dtype=i32),
+            gather_cols=_sds(sub, p, w, dtype=i32), r=_sds(rep, M),
+            b=_sds(rep, M), n=N, p=p, w=w, solve_kernel="fused",
+            solve_block=512)
+        idx = (halo.pack_idx.shape if layout == "neighbour" else (p, 0, 0))
+        fn = ddkf._shardmap_fn(mesh, ("sub",), 60, layout, "scatter",
+                               halo.perms if layout == "neighbour" else (),
+                               residual_history=False,
+                               return_per_device=True)
+        compiled = fn.lower(packed, _sds(sub, *idx, dtype=i32),
+                            _sds(sub, *idx, dtype=i32),
+                            _sds(rep, dtype=F32)).compile()
+        padded = f"f32[1,{M},1280]"       # one subdomain per chip
+    text = compiled.as_text()
+    assert _kernels(compiled) == 2          # schwarz_fwd + schwarz_bwd
+    assert padded not in text
+    if layout == "vmapped":
+        # one copy of A_loc in its (8, 128) tiles is 224.7 MB
+        assert compiled.memory_analysis().temp_size_in_bytes <= 230e6
